@@ -17,9 +17,11 @@ benchmarks never see:
   compile before result one; a warm process rebuilds the runner from the
   persisted plan artifact and loads serialized executables
   (``cold_first_result_s`` vs ``warm_first_result_s`` in the section
-  config, measured at batch=100 with a fresh tmp cache so "cold" is
-  honestly cold — including jax's own persistent compilation cache,
-  which build_service points under the same tmp dir).
+  config, measured at batch=100 with a fresh tmp cache dir: "cold" has
+  no plan artifact and no AOT executables.  jax's own persistent
+  compilation cache is not moved — it stays where
+  ``JAX_COMPILATION_CACHE_DIR`` or the checkout's ``out/jax_cache`` puts
+  it, so XLA compiles it already holds are reused by both runs).
 """
 from __future__ import annotations
 
@@ -108,7 +110,7 @@ def run(n_events: int = 1_000_000):
                 metrics=svc.runner.metrics)
 
         # cold vs warm first-result: same fresh cache dir twice, two
-        # "processes" (fresh runner + fresh jax cache dir under tmp)
+        # "processes" (fresh runner; the AOT cache dir starts empty)
         fr_dir = f"{tmp}/firstresult"
         t_cold, svc_c = _first_result(fr_dir, FIRST_RESULT_BATCH)
         assert svc_c.plan_source == "cold"

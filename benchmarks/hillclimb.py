@@ -38,7 +38,9 @@ def main():
         cmd += ["--micro", str(args.micro)]
     for kv in args.set:
         cmd += ["--set", kv]
-    p = subprocess.run(cmd, capture_output=True, text=True)
+    # the dry run compiles for host devices only: keep it off any chip
+    p = subprocess.run(cmd, capture_output=True, text=True,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if p.returncode != 0:
         print(p.stdout[-2000:], p.stderr[-2000:])
         sys.exit(1)

@@ -50,8 +50,11 @@ def _roofline(roofline_table, out_dir: str = "out/dryrun") -> None:
             if not cost:
                 cmd += ["--skip-unrolled"]
             try:
+                # the dry run compiles for host devices only: keep the
+                # child off any accelerator this process may hold
                 subprocess.run(cmd, timeout=2400, check=False,
-                               capture_output=True)
+                               capture_output=True,
+                               env={**os.environ, "JAX_PLATFORMS": "cpu"})
             except subprocess.TimeoutExpired:
                 pass  # run_cell records its own failure JSON when it can
     roofline_table.run(out_dir)
@@ -72,12 +75,12 @@ def main() -> None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={int(ndev)}").strip()
 
-    # warm XLA compiles across benchmark runs (best-effort; opt out with
-    # REPRO_BENCH_JAX_CACHE=0).  fig_latency's cold/warm measurement is
-    # unaffected: build_service repoints the cache under its fresh tmp dir.
+    # warm XLA compiles across benchmark runs (JAX_COMPILATION_CACHE_DIR or
+    # the checkout's fixed out/jax_cache; opt out with
+    # REPRO_BENCH_JAX_CACHE=0)
     if os.environ.get("REPRO_BENCH_JAX_CACHE") != "0":
         from repro.serve import enable_jax_compilation_cache
-        enable_jax_compilation_cache("out/jax_cache")
+        enable_jax_compilation_cache()
 
     from benchmarks import (common, fig7_throughput, fig8_keyed_scaling,
                             fig8_ysb_scaling, fig9_latency, fig10_fusion,

@@ -419,6 +419,18 @@ class Runner:
         sh = NamedSharding(self.policy.mesh, P(self.policy.axis))
         return _tm(lambda x: jax.device_put(x, sh), tree)
 
+    def _unit_flags(self, flags):
+        """Pin the per-unit flags ``(K, n_segs)`` (the sparse step's
+        ``seg_dirty`` output, the metrics accumulator's and revision
+        step's input) to one sharding under mesh placement — keys split
+        when keyed, replicated when single — so the steps compiled ahead
+        of time agree on it.  Local placement: unchanged."""
+        mesh = self.policy.mesh
+        if mesh is None:
+            return flags
+        return jax.lax.with_sharding_constraint(flags, NamedSharding(
+            mesh, P(self.policy.axis) if self.policy.keyed else P()))
+
     # every configuration degree of freedom the staged steps close over;
     # _cache_key is built from exactly these (in this order) so the staging
     # cache can never be keyed on less than the traces depend on.  The
@@ -473,6 +485,20 @@ class Runner:
             fn, mesh=mesh,
             in_specs=(P(axis),) + (buf_spec,) * n_buf_args,
             out_specs=P(axis), check_rep=False)
+
+    def _per_key(self, fn):
+        """Run ``fn(values, valid)`` — per-key work over whole key rows —
+        on each device's own keys under mesh placement.  A Pallas kernel
+        cannot be partitioned automatically, so the change-detection
+        kernel must sit inside shard_map: keys split when keyed, the
+        replicated buffer of a single-key stream read whole everywhere."""
+        mesh = self.policy.mesh
+        if mesh is None:
+            return fn
+        from jax.experimental.shard_map import shard_map
+        spec = P(self.policy.axis) if self.policy.keyed else P()
+        return shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_rep=False)
 
     # -- chunk ingest --------------------------------------------------------
     def _ingest(self, chunks: Dict[str, SnapshotGrid]) -> Dict[str, tuple]:
@@ -770,7 +796,7 @@ class Runner:
                     return sparse_compact.seg_dirty(
                         mats, [g] * len(mats), n_segs)
 
-                sd = jax.vmap(one_key)(fv, fm)           # (K, n_segs)
+                sd = self._per_key(jax.vmap(one_key))(fv, fm)  # (K, n_segs)
                 # buffer position 0: carried change flag (its diff partner
                 # is one tick before the buffer); with no tail the carried
                 # 1-tick snapshot supplies the partner
@@ -803,6 +829,7 @@ class Runner:
                 seg_dirty = jnp.ones((K, n_segs), bool)  # input-free: dense
             if force_first:
                 seg_dirty = seg_dirty.at[:, 0].set(True)
+            seg_dirty = self._unit_flags(seg_dirty)
             full = sharded(seg_dirty.reshape(U),
                            *[bufs[nm] for nm in names])
             full = {o: (_tm(lambda x: x.reshape(
@@ -955,7 +982,8 @@ class Runner:
                                     jnp.int32))
                 steps.append(entry(
                     "obs_accum", key, fn,
-                    (mstate, jnp.zeros((self._K, self.n_segs), bool))))
+                    (mstate, self._unit_flags(
+                        jnp.zeros((self._K, self.n_segs), bool)))))
         else:
             fn = self._dense_step()
             key = self._cache_key("dense")
@@ -964,8 +992,9 @@ class Runner:
             fn = self._revision_step()
             key = self._cache_key("revise")
             steps.append(entry("revise", key, fn,
-                               (tails, chunk_in,
-                                jnp.zeros((self._K, self.n_segs), bool))))
+                               (tails, chunk_in, self._unit_flags(
+                                   jnp.zeros((self._K, self.n_segs),
+                                             bool)))))
         return steps
 
     def chunk_fn(self, variant: str = "steady", chunks: Optional[Dict] = None):
@@ -1506,7 +1535,8 @@ class Runner:
                                 np.asarray, self._strip(tails[name]))
             sd = np.asarray(sd, bool).reshape(K, self.n_segs)
             n_units += int(sd.sum())
-            outs, tails = step(tails, chunk_in, jnp.asarray(sd))
+            outs, tails = step(tails, chunk_in,
+                               self._unit_flags(jnp.asarray(sd)))
             last_in, last_sd, last_outs = chunk_in, sd, outs
             res = {}
             for o, (v, m) in self._postprocess(outs).items():

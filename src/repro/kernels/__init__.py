@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (window reductions, fused change detection), their
+jnp oracles (:mod:`.ref`) and the backend dispatch (:mod:`.ops`)."""

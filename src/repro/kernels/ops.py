@@ -1,17 +1,19 @@
 """Jit'd dispatch wrappers around the Pallas window-reduction kernels.
 
 ``ops`` is the only kernel entry point the rest of the package uses; it
-chooses between the Pallas kernel and the pure-jnp reference according to
-backend and problem size:
+chooses between the Pallas kernel and the pure-jnp reference by backend
+(:func:`use_pallas`), unless the caller passes ``pallas`` explicitly:
 
-* On TPU: Pallas (interpret=False).
-* On CPU (this container): Pallas with interpret=True when
-  ``REPRO_PALLAS_INTERPRET=1`` (tests force this), else the jnp reference —
-  interpret mode executes the kernel body per-block in Python and is far too
-  slow for the 10⁸-event benchmark runs, while the jnp path lowers to the
-  same XLA ops the TPU kernels implement manually.
-* Tiny windows (< _SMALL_W) skip Van Herk for a direct shift-combine; the
-  striping overhead exceeds the O(W) cost there.
+* On TPU: the Pallas kernels, compiled (never interpreted).
+* Elsewhere: the jnp reference, which lowers to the same XLA ops the
+  kernels implement by hand — unless ``REPRO_PALLAS_INTERPRET=1`` (the
+  tests set it), which runs the kernels in interpret mode: far too slow
+  for benchmark-scale runs, but the same kernel bodies the chip compiles.
+* Windows below ``_SMALL_W`` take no kernel for max/min (a direct
+  shift-combine in jnp) and the prefix-scan path for sums: at that size
+  the window kernel's block overhead exceeds the O(W) work.
+
+:func:`repro.kernels.sparse_compact.seg_dirty` dispatches the same way.
 """
 from __future__ import annotations
 
@@ -51,9 +53,11 @@ def sliding_sum(x: jax.Array, valid: jax.Array, window: int,
       scan (Pallas kernel on TPU), then ``P[t] - P[t-W]`` as an XLA slice.
       FP32 CAVEAT: the cancellation error grows like ``eps·t·mean`` with
       stream position — unusable beyond ~10⁶ ticks of O(100) values.
-    * ``algo='block'`` — beyond-paper numerical fix (DESIGN.md): block-local
-      prefix/suffix sums with block size = W (the Van Herk structure with
-      ``combine=+``).  Error is bounded by the *window* content
+    * ``algo='block'`` — beyond-paper numerical fix (DESIGN.md): every
+      output sums only its own window — block-local prefix/suffix sums with
+      block size = W (the Van Herk structure with ``combine=+``) in jnp, the
+      binary window decomposition of :func:`window_reduce.sliding_assoc` in
+      the kernel.  Error is bounded by the *window* content
       (``eps·W·mean``), independent of stream length.  Default.
     """
     pallas = use_pallas() if pallas is None else pallas

@@ -8,23 +8,24 @@ Two kernels cover every built-in reduction:
   over all ticks; the subtract itself is a cheap XLA slice, so the kernel is
   the bandwidth-bound scan.
 
-* :func:`sliding_assoc` — Van Herk / Gil-Werman sliding reduce for
-  non-invertible associative ops (max/min): the timeline is striped into
-  rows of width W (lane axis); a prefix scan of the current row and a suffix
-  scan of the previous row combine into the exact W-window reduce with O(1)
-  work per element and 2 reads per element.
+* :func:`sliding_assoc` — exact W-tick sliding reduce for any associative
+  combine (sum, max, min).  Each output block is reduced from itself and
+  the block before it: log-step doubling builds ``f_s[t] = combine over
+  [t-s+1, t]`` for ``s = 1, 2, 4, …`` and the output combines the disjoint
+  power-of-two ranges of W's binary expansion, so every tick reads at most
+  ``2·log2(W)`` rolled vectors.  Sums reduce at most W values per output
+  (error bounded by the window's content, independent of stream length).
 
-TPU mapping notes (kernels are *validated* with ``interpret=True`` on CPU —
-this container has no TPU — and *targeted* at TPU):
+TPU mapping (checked by compiling for a described v5e chip in
+tests/test_tpu_compile.py; CPU runs them with ``interpret=True``):
 
-* Blocks are ``(C, B)`` with C = channel count on the sublane axis and B on
-  the lane axis; wrappers pad B to a multiple of 128 (MXU/VPU lane width)
-  and C to 8 sublanes when C > 1.
-* The grid is 1-D and sequential on TPU, which makes the VMEM carry scratch
-  legal (scratch persists across grid steps).
-* ``associative_scan``/``cumsum`` inside the kernel body lower to
-  log-depth vector ops on the VPU; window widths that are not multiples of
-  128 relayout (performance, not correctness).
+* Blocks are ``(C, B)``: C = channel rows on the sublane axis (the whole
+  channel dim, so any C is a legal block), B on the lane axis a multiple of
+  128 (:func:`_block_for`).  The wrappers pad T up to a multiple of B.
+* Shifts along time are ``pltpu.roll`` lane rotations masked by lane
+  position — no unaligned slices, no ``cumsum`` in the kernel body.
+* The grid is 1-D; the prefix scan's carry lives in VMEM scratch, which
+  persists across its sequential grid steps.
 """
 from __future__ import annotations
 
@@ -33,17 +34,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU scratch memory spaces; present in jax 0.8
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["prefix_scan", "sliding_assoc", "DEFAULT_BLOCK"]
 
 DEFAULT_BLOCK = 1024  # lanes per grid step for the prefix scan
+_LANES = 128
+
+
+def _block_for(n: int) -> int:
+    """Smallest multiple of the 128-lane tile covering ``n`` ticks."""
+    return max(_LANES, -(-int(n) // _LANES) * _LANES)
 
 
 # ---------------------------------------------------------------------------
@@ -57,8 +58,13 @@ def _prefix_scan_kernel(x_ref, out_ref, carry_ref):
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    x = x_ref[...].astype(jnp.float32)          # (C, B)
-    p = jnp.cumsum(x, axis=-1) + carry_ref[...]  # carry (C, 1) broadcasts
+    p = x_ref[...].astype(jnp.float32)            # (C, B)
+    lane = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
+    s = 1
+    while s < p.shape[-1]:                         # Hillis-Steele scan
+        p = p + jnp.where(lane >= s, pltpu.roll(p, s, 1), 0.0)
+        s *= 2
+    p = p + carry_ref[...]                         # carry (C, 1) broadcasts
     out_ref[...] = p
     carry_ref[...] = p[:, -1:]
 
@@ -67,41 +73,47 @@ def prefix_scan(x: jax.Array, block: int = DEFAULT_BLOCK,
                 interpret: bool = True) -> jax.Array:
     """Inclusive f32 prefix sum along the last axis of ``x: (C, T)``.
 
-    T is padded to a multiple of ``block``; the pad region is zeros so the
-    carry is unaffected, and the wrapper slices the result back.
+    T is padded to a multiple of ``block`` (itself rounded up to the lane
+    tile); the pad region is zeros so the carry is unaffected, and the
+    wrapper slices the result back.
     """
     C, T = x.shape
+    block = _block_for(block)
     Tp = -(-T // block) * block
     xp = jnp.pad(x, ((0, 0), (0, Tp - T)))
-    grid = Tp // block
 
     out = pl.pallas_call(
         _prefix_scan_kernel,
-        grid=(grid,),
+        grid=(Tp // block,),
         in_specs=[pl.BlockSpec((C, block), lambda k: (0, k))],
         out_specs=pl.BlockSpec((C, block), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((C, Tp), jnp.float32),
-        scratch_shapes=[_VMEM((C, 1), jnp.float32)] if _VMEM else None,
+        scratch_shapes=[pltpu.VMEM((C, 1), jnp.float32)],
         interpret=interpret,
     )(xp)
     return out[:, :T]
 
 
 # ---------------------------------------------------------------------------
-# Kernel 2: Van Herk / Gil-Werman sliding associative reduce
+# Kernel 2: sliding associative reduce by binary window decomposition
 # ---------------------------------------------------------------------------
 
-def _vanherk_kernel(prev_ref, cur_ref, out_ref, *, combine, identity):
-    prev = prev_ref[...]   # (C, W) — row k-1 of the striped timeline
-    cur = cur_ref[...]     # (C, W) — row k
-    C, W = cur.shape
-    prefix = jax.lax.associative_scan(combine, cur, axis=1)
-    suffix = jax.lax.associative_scan(combine, prev, axis=1, reverse=True)
-    # out[t = kW + j] reduces [t-W+1, t] = prev[j+1:] ∪ cur[:j+1]
-    #               = combine(suffix[j+1] (identity when j = W-1), prefix[j])
-    suf = jnp.concatenate(
-        [suffix[:, 1:], jnp.full((C, 1), identity, cur.dtype)], axis=-1)
-    out_ref[...] = combine(suf, prefix)
+def _sliding_kernel(prev_ref, cur_ref, out_ref, *, window, combine):
+    v = jnp.concatenate([prev_ref[...], cur_ref[...]], axis=-1)  # (C, 2B)
+    B = cur_ref.shape[-1]
+    f, acc, off, s = v, None, 0, 1
+    # f = combine over [t-s+1, t]; acc = combine over [t-off+1, t].  Lanes
+    # of the current block (>= B) never read past lane 0 (W - 1 <= B), so
+    # the rotations' wrap-around only reaches discarded lanes.
+    while s <= window:
+        if window & s:
+            part = f if off == 0 else pltpu.roll(f, off, 1)
+            acc = part if acc is None else combine(acc, part)
+            off += s
+        s *= 2
+        if s <= window:
+            f = combine(f, pltpu.roll(f, s // 2, 1))
+    out_ref[...] = acc[:, B:]
 
 
 def sliding_assoc(x: jax.Array, window: int, combine, identity,
@@ -110,28 +122,26 @@ def sliding_assoc(x: jax.Array, window: int, combine, identity,
 
     ``out[:, t] = combine over x[:, max(0, t-window+1) : t+1]``.
 
-    The wrapper left-pads one full row of ``identity`` (so row k-1 always
+    The wrapper left-pads one block of ``identity`` (so block k-1 always
     exists and leading partial windows are exact) and right-pads T to a
-    multiple of W.
+    multiple of the block, which spans at least ``window - 1`` ticks.
     """
     C, T = x.shape
     W = int(window)
     if W <= 1:
         return x
-    Tp = -(-T // W) * W
-    xp = jnp.pad(x, ((0, 0), (W, Tp - T)), constant_values=identity)
-    rows = Tp // W  # output rows; padded input has rows+1 rows
+    B = _block_for(W - 1)
+    Tp = -(-T // B) * B
+    xp = jnp.pad(x, ((0, 0), (B, Tp - T)), constant_values=identity)
 
-    kern = functools.partial(_vanherk_kernel, combine=combine,
-                             identity=identity)
     out = pl.pallas_call(
-        kern,
-        grid=(rows,),
+        functools.partial(_sliding_kernel, window=W, combine=combine),
+        grid=(Tp // B,),
         in_specs=[
-            pl.BlockSpec((C, W), lambda k: (0, k)),      # prev row (padded idx k)
-            pl.BlockSpec((C, W), lambda k: (0, k + 1)),  # cur row (padded idx k+1)
+            pl.BlockSpec((C, B), lambda k: (0, k)),      # previous block
+            pl.BlockSpec((C, B), lambda k: (0, k + 1)),  # current block
         ],
-        out_specs=pl.BlockSpec((C, W), lambda k: (0, k)),
+        out_specs=pl.BlockSpec((C, B), lambda k: (0, k)),
         out_shape=jax.ShapeDtypeStruct((C, Tp), x.dtype),
         interpret=interpret,
     )(xp, xp)

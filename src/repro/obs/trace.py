@@ -34,11 +34,8 @@ __all__ = ["Tracer"]
 def _jax_annotation(name: str):
     if os.environ.get("REPRO_OBS_JAX_TRACE", "0") != "1":
         return contextlib.nullcontext()
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    import jax
+    return jax.profiler.TraceAnnotation(name)
 
 
 class Tracer:

@@ -20,13 +20,17 @@ planning or compiling anything.
 
 The complementary :func:`enable_jax_compilation_cache` turns on jax's own
 persistent compilation cache (HLO-hash keyed): it does not skip tracing,
-but makes genuinely cold starts cheaper too.  Both are best-effort — a
-backend that cannot cache degrades to plain compilation.
+but makes genuinely cold starts cheaper too.  Its directory is placed from
+outside (``JAX_COMPILATION_CACHE_DIR``) or is one fixed path in the
+checkout, so the same program finds its entries again.  The executable
+cache is best-effort — a corrupt or stale entry degrades to a compile.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
+import pathlib
 import pickle
 import tempfile
 from typing import Dict, Optional
@@ -39,18 +43,46 @@ __all__ = ["ExecutableCache", "aot_compile", "enable_jax_compilation_cache",
            "step_fingerprint"]
 
 
-def enable_jax_compilation_cache(path: str = "out/jax_cache") -> bool:
-    """Best-effort enable of jax's persistent compilation cache at
-    ``path`` (min-size/min-time thresholds dropped so CPU-scale entries
-    qualify).  Returns whether the config took."""
-    try:
+# the checkout's fixed compile-cache path: resolved from this file, never
+# from the working directory, so every process of the repo shares it
+DEFAULT_JAX_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[3] / "out" / "jax_cache")
+
+
+def enable_jax_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    no other directory is set here; otherwise the cache goes to
+    :data:`DEFAULT_JAX_CACHE_DIR`.  The min-size/min-time thresholds are
+    dropped so CPU-scale entries qualify.  Failures raise."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_JAX_CACHE_DIR
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", os.path.abspath(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return True
-    except Exception:
-        return False
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
+
+
+@contextlib.contextmanager
+def _persistent_cache_off():
+    """Compile with jax's persistent compilation cache bypassed.  An
+    executable loaded from that cache does not survive
+    ``serialize_executable`` on XLA:CPU (its kernels are missing once
+    deserialized), so every executable this module persists is compiled
+    afresh."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        cc.reset_cache()
 
 
 def _backend_tag() -> tuple:
@@ -173,7 +205,12 @@ def aot_compile(runner, cache: Optional[ExecutableCache] = None, *,
         label = step["label"]
         if label in report:
             continue
-        compiled = step["fn"].lower(*step["args"]).compile()
+        lowered = step["fn"].lower(*step["args"])
+        if cache is None:
+            compiled = lowered.compile()
+        else:
+            with _persistent_cache_off():
+                compiled = lowered.compile()
         runner.install_executable(step["key"], compiled, label=label,
                                   how="compiled", donate=step["donate"])
         report[label] = "compiled"

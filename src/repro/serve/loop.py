@@ -43,8 +43,7 @@ from ..engine.runner import BodySpec
 from ..ingest import IngestRunner
 from ..multiquery import SharedPlanCache
 from ..obs import Metrics, log_buckets
-from .aot import (ExecutableCache, aot_compile, enable_jax_compilation_cache,
-                  step_fingerprint)
+from .aot import ExecutableCache, aot_compile, step_fingerprint
 from .ring import AdmissionRing
 
 __all__ = ["ServeLoop", "build_service", "plan_artifact_of",
@@ -261,8 +260,7 @@ def build_service(query, *, out_len: int,
                   policy: Optional[ExecPolicy] = None,
                   n_keys: Optional[int] = None, segs_per_chunk: int = 1,
                   cache_dir: Optional[str] = None,
-                  metrics: Optional[Metrics] = None,
-                  jax_cache: bool = True) -> ServeLoop:
+                  metrics: Optional[Metrics] = None) -> ServeLoop:
     """Build a warmed :class:`ServeLoop` for one query.
 
     With ``cache_dir`` the two persisted caches live under it:
@@ -275,9 +273,12 @@ def build_service(query, *, out_len: int,
     ``loop.plan_source == "warm"`` and the tracer's compile record stays
     empty.  Any cache miss falls back to the cold path transparently.
 
-    ``jax_cache`` additionally points jax's own persistent compilation
-    cache under ``cache_dir`` so even cold XLA compiles warm across
-    sessions (best-effort; no-op where unsupported).
+    jax's own persistent compilation cache is process-wide and is not
+    touched here: it is on where ``JAX_COMPILATION_CACHE_DIR`` is set, or
+    after :func:`repro.serve.enable_jax_compilation_cache`.
+
+    The query compiles with the backend's kernel choice
+    (:func:`repro.kernels.ops.use_pallas`): the Pallas kernels on TPU.
     """
     node = getattr(query, "node", query)
     policy = policy if policy is not None else ExecPolicy(body="sparse")
@@ -289,8 +290,6 @@ def build_service(query, *, out_len: int,
         persist=os.path.join(cache_dir, "plans.pkl") if cache_dir else None)
     exec_cache = (ExecutableCache(os.path.join(cache_dir, "aot"))
                   if cache_dir else None)
-    if cache_dir and jax_cache:
-        enable_jax_compilation_cache(os.path.join(cache_dir, "jax_cache"))
     root = plan_cache.intern(node)
     fp = ir.fingerprint(root)
 
@@ -305,8 +304,7 @@ def build_service(query, *, out_len: int,
                for label, _ in r.aot_keys()):
             runner, how = r, "warm"
     if runner is None:
-        exe = qc.compile_query(root, out_len=out_len, pallas=False,
-                               sparse=policy.sparse)
+        exe = qc.compile_query(root, out_len=out_len, sparse=policy.sparse)
         runner = Runner(exe, policy, n_keys=n_keys,
                         segs_per_chunk=segs_per_chunk, metrics=metrics)
         plan_cache.store_artifact(fp, out_len, plan_artifact_of(runner))

@@ -54,6 +54,35 @@ def test_sliding_sum(T, C, W, algo, pallas):
     np.testing.assert_allclose(np.asarray(n), np.asarray(nr), atol=0.5)
 
 
+@pytest.mark.parametrize("T,C,W", SHAPES + [(1300, 2, 1000), (400, 3, 50),
+                                             (300, 2, 20)])
+@pytest.mark.parametrize("algo", ["block", "soe"])
+def test_sliding_sum_kernel_exact_on_integers(T, C, W, algo):
+    """On integer-valued data every float sum is exact, so the kernels
+    (their own summation order) must equal the jnp path bit for bit."""
+    rng = np.random.default_rng(T * 3 + W)
+    x = jnp.asarray(rng.integers(-50, 50, size=(C, T)).astype(np.float32))
+    valid = jnp.asarray(rng.random(T) > 0.3)
+    s_k, n_k = ops.sliding_sum(x, valid, W, pallas=True, algo=algo)
+    s_r, n_r = ops.sliding_sum(x, valid, W, pallas=False, algo=algo)
+    assert np.array_equal(np.asarray(s_k), np.asarray(s_r))
+    assert np.array_equal(np.asarray(n_k), np.asarray(n_r))
+
+
+@pytest.mark.parametrize("W", [8, 20, 50, 127, 128, 129, 1000])
+@pytest.mark.parametrize("op", ["max", "min"])
+def test_sliding_assoc_kernel_matches_block_ref(W, op):
+    """Max/min are exact in any order: kernel == jnp block path, at window
+    sizes on and off the 128-lane tile."""
+    rng = np.random.default_rng(W)
+    x = jnp.asarray(rng.normal(size=(2, 1500)).astype(np.float32))
+    valid = jnp.asarray(rng.random(1500) > 0.3)
+    v_k, a_k = ops.sliding_assoc(x, valid, W, op, pallas=True)
+    v_r, a_r = ops.sliding_assoc(x, valid, W, op, pallas=False)
+    assert np.array_equal(np.asarray(v_k), np.asarray(v_r))
+    assert np.array_equal(np.asarray(a_k), np.asarray(a_r))
+
+
 def test_block_beats_soe_numerics():
     """The beyond-paper block algorithm must bound error by window content;
     SoE error grows with stream length (DESIGN.md §2)."""

@@ -314,6 +314,28 @@ def test_policy_sparse_mesh_multidev_bit_identical():
     assert "POLICY_MESH_OK" in out
 
 
+def test_served_mesh_placements_bit_identical_on_4_devices():
+    """``chip_smoke.py --chips 4``'s phases on 4 host devices: served
+    runners under mesh placement (keys sharded; one stream's segments
+    sharded) and the multi-hop one-shot halo, each bit for bit against
+    one device.  Pins the AOT steps agreeing on the sharded dirty flags
+    (the metrics accumulator was once lowered for single-device flags)."""
+    out = _run("""
+        import os, sys
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        sys.path.insert(0, ".")
+        import jax
+        import chip_smoke as cs
+        args = cs.parse_args(["--chips", "4", "--keys", "8", "--win", "50",
+                              "--seg", "32", "--segs", "4", "--chunks", "3"])
+        res = cs.run_four(args, jax.devices()[:4])
+        assert all(r["identical"] for r in res.values()), res
+        assert res["halo"]["hops"] == 2, res
+        print("MESH_OK")
+    """)
+    assert "MESH_OK" in out
+
+
 def test_sparse_union_session_mesh_multidev():
     """Acceptance: a sparse union session (merged ChangePlan, keyed × mesh)
     is bit-identical to its dense solo counterparts on integer data."""

@@ -362,6 +362,51 @@ def test_pass_serving_noop_on_unserved_runner():
 
 
 # ---------------------------------------------------------------------------
+# jax's persistent compilation cache: placed from outside, else one fixed path
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = """
+import os, jax, jax.numpy as jnp
+from repro.serve import enable_jax_compilation_cache
+from repro.serve.aot import DEFAULT_JAX_CACHE_DIR
+path = enable_jax_compilation_cache()
+assert path == jax.config.jax_compilation_cache_dir, path
+want = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_JAX_CACHE_DIR
+assert path == want, (path, want)
+if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+    assert os.listdir(path), "no cache entry written"
+print("CACHE_AT", path)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_jax_cache_placed_from_outside_or_fixed(tmp_path, from_env):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, compiles land there and no
+    other directory is set; without it the cache is the checkout's fixed
+    ``out/jax_cache``, whatever the working directory."""
+    import subprocess
+    import sys
+    from repro.serve.aot import DEFAULT_JAX_CACHE_DIR
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = src
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    cwd = tmp_path / "elsewhere"
+    cwd.mkdir()
+    want = str(tmp_path / "cache") if from_env else DEFAULT_JAX_CACHE_DIR
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                       cwd=cwd, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr
+    assert f"CACHE_AT {want}" in p.stdout
+    assert os.path.isabs(want) and not os.listdir(cwd)
+
+
+# ---------------------------------------------------------------------------
 # launch/serve.py: prefill compiled once per run
 # ---------------------------------------------------------------------------
 
