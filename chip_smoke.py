@@ -24,6 +24,7 @@ there; this script sets no cache directory of its own.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -39,24 +40,37 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 RTOL = 1e-4
 ATOL = 1e-2
 
+# share of the event path's events delayed (inside the lateness bound)
+LATE_FRAC = 0.03
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Scale of one run.  The defaults are the deployment every run of
+    this script serves (:data:`DEPLOYMENT`); only tests build others."""
+    keys: int = 4096
+    win: int = 1000
+    seg: int = 256          # output ticks per segment
+    segs: int = 8           # segments per chunk
+    chunks: int = 8
+    sample: int = 64        # keys checked against the reference
+    event_keys: int = 64
+    event_chunks: int = 3
+    lateness: int = 64
+
+    @property
+    def span(self) -> int:
+        """Ticks per key per chunk."""
+        return self.seg * self.segs
+
+
+DEPLOYMENT = Sizes()
+
 
 def parse_args(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--keys", type=int, default=4096)
-    ap.add_argument("--win", type=int, default=1000)
-    ap.add_argument("--seg", type=int, default=256,
-                    help="output ticks per segment")
-    ap.add_argument("--segs", type=int, default=8,
-                    help="segments per chunk (chunk = seg*segs ticks/key)")
-    ap.add_argument("--chunks", type=int, default=8)
-    ap.add_argument("--sample", type=int, default=64,
-                    help="keys checked against the reference")
-    ap.add_argument("--event-keys", type=int, default=64)
-    ap.add_argument("--event-chunks", type=int, default=3)
-    ap.add_argument("--late-frac", type=float, default=0.03)
-    ap.add_argument("--lateness", type=int, default=64)
     return ap.parse_args(argv)
 
 
@@ -163,11 +177,11 @@ def check_against_reference(got, ref, value, what: str) -> dict:
 
 # -- chunk path ----------------------------------------------------------------
 
-def build(app, args, policy, n_keys):
+def build(app, sz: Sizes, policy, n_keys):
     from repro.serve import build_service
     t0 = time.perf_counter()
-    svc = build_service(app.query, out_len=args.seg, policy=policy,
-                        n_keys=n_keys, segs_per_chunk=args.segs)
+    svc = build_service(app.query, out_len=sz.seg, policy=policy,
+                        n_keys=n_keys, segs_per_chunk=sz.segs)
     return svc, time.perf_counter() - t0
 
 
@@ -184,15 +198,14 @@ def serve_chunks(svc, value, valid, span, n_chunks, rows=None,
                  keyed=True):
     """Serve every chunk; gather output rows ``rows`` (all when None) to
     the host.  Returns ``(value, valid, host wall seconds per chunk — the
-    served call plus the gather —, devices the last output was spread
-    over)``."""
+    served call plus the gather —, the last output's sharding)``."""
     import jax.numpy as jnp
     idx = None if rows is None else jnp.asarray(rows)
     vs, ms, walls = [], [], []
     t0 = time.perf_counter()
     for out in svc.serve(chunks_of(value, valid, span, n_chunks, keyed)):
         v, m = out.value, out.valid
-        n_dev = len(v.sharding.device_set)
+        sharding = v.sharding
         if idx is not None:
             v, m = v[idx], m[idx]
         vs.append(np.asarray(v))
@@ -200,27 +213,25 @@ def serve_chunks(svc, value, valid, span, n_chunks, rows=None,
         walls.append(time.perf_counter() - t0)
         t0 = time.perf_counter()
     return (np.concatenate(vs, axis=-1), np.concatenate(ms, axis=-1), walls,
-            n_dev)
+            sharding)
 
 
-def phase_keyed(app, args, body, value, valid, sample, ref):
+def phase_keyed(app, sz: Sizes, body, value, valid, sample, ref):
     import jax
     from repro.engine import ExecPolicy
     K = value.shape[0]
-    span = args.seg * args.segs
-    svc, compile_s = build(app, args, ExecPolicy(body=body, keys="vmapped"),
-                           K)
+    svc, compile_s = build(app, sz, ExecPolicy(body=body, keys="vmapped"), K)
     kernel = kernel_in_sparse_step(svc) if body == "sparse" else None
     if body == "sparse" and jax.default_backend() == "tpu":
         assert kernel, "seg_dirty kernel missing from the sparse step HLO"
-    gv, gm, wall, _ = serve_chunks(svc, value, valid, span, args.chunks,
+    gv, gm, wall, _ = serve_chunks(svc, value, valid, sz.span, sz.chunks,
                                    rows=sample)
     stats = check_against_reference(
         {int(k): (gv[i], gm[i]) for i, k in enumerate(sample)}, ref, value,
         f"keyed {body}")
-    events = int(valid[:, :span * args.chunks].sum())
-    say(f"phase=keyed body={body} keys={K} chunks={args.chunks} "
-        f"ticks_per_chunk={span} win={args.win} events={events} "
+    events = int(valid[:, :sz.span * sz.chunks].sum())
+    say(f"phase=keyed body={body} keys={K} chunks={sz.chunks} "
+        f"ticks_per_chunk={sz.span} win={sz.win} events={events} "
         f"compile_s={compile_s!r} chunk_wall_s={wall!r} "
         f"plan={svc.plan_source} aot={svc.aot_report} "
         f"sparse_step_kernel={kernel} reference={stats}")
@@ -240,21 +251,20 @@ def arrival_order(valid, late_frac, lateness, rng):
     return t[order], k[order], int((delay > 0).sum())
 
 
-def phase_events(app, args, rng):
+def phase_events(app, sz: Sizes, seed: int, rng):
     from repro.core.stream import Event
     from repro.engine import ExecPolicy
     from repro.serve import build_service
-    K, span = args.event_keys, args.seg * args.segs
-    T = span * args.event_chunks
-    value, valid = keyed_stream(app, K, T, args.seed + 1)
+    K = sz.event_keys
+    value, valid = keyed_stream(app, K, sz.span * sz.event_chunks, seed + 1)
     t0 = time.perf_counter()
-    svc = build_service(app.query, out_len=args.seg,
+    svc = build_service(app.query, out_len=sz.seg,
                         policy=ExecPolicy(body="sparse", keys="vmapped"),
-                        n_keys=K, segs_per_chunk=args.segs)
+                        n_keys=K, segs_per_chunk=sz.segs)
     compile_s = time.perf_counter() - t0
-    svc.attach_events(lateness=args.lateness, policy="drop",
+    svc.attach_events(lateness=sz.lateness, policy="drop",
                       capacity=1 << 16, shed="block")
-    ts, ks, n_late = arrival_order(valid, args.late_frac, args.lateness, rng)
+    ts, ks, n_late = arrival_order(valid, LATE_FRAC, sz.lateness, rng)
     sealed = []
     t0 = time.perf_counter()
     for i, (t, k) in enumerate(zip(ts.tolist(), ks.tolist())):
@@ -267,15 +277,15 @@ def phase_events(app, args, rng):
     late = snap["ingest.late_events"]["value"]
     assert late == 0, f"{late} events fell behind the sealed frontier"
     sealed.sort(key=lambda s: s.chunk)
-    assert [s.chunk for s in sealed] == list(range(args.event_chunks)), \
+    assert [s.chunk for s in sealed] == list(range(sz.event_chunks)), \
         [s.chunk for s in sealed]
     gv = np.concatenate([np.asarray(s.outputs.value) for s in sealed], 1)
     gm = np.concatenate([np.asarray(s.outputs.valid) for s in sealed], 1)
-    ref = reference(app, value, valid, range(K), span)
+    ref = reference(app, value, valid, range(K), sz.span)
     stats = check_against_reference(
         {k: (gv[k], gm[k]) for k in range(K)}, ref, value, "event path")
-    say(f"phase=events keys={K} chunks={args.event_chunks} "
-        f"events={ts.size} out_of_order={n_late} lateness={args.lateness} "
+    say(f"phase=events keys={K} chunks={sz.event_chunks} "
+        f"events={ts.size} out_of_order={n_late} lateness={sz.lateness} "
         f"compile_s={compile_s!r} ingest_wall_s={wall!r} "
         f"reference={stats}")
     return stats
@@ -283,65 +293,91 @@ def phase_events(app, args, rng):
 
 # -- four chips: mesh placement vs one chip -------------------------------------
 
-def _sharded_over(x, n: int) -> bool:
-    return len(x.sharding.device_set) == n
+def _check_spread(x, n: int, sharded: bool, what: str) -> None:
+    """``x`` lives on all ``n`` devices: split over them (``sharded``, one
+    equal shard each) or a full copy on every one.  Catches state or
+    outputs that all land on device 0."""
+    sh = x.sharding
+    assert len(sh.device_set) == n, f"{what} on {len(sh.device_set)} of {n}"
+    if sharded and n > 1:
+        assert not sh.is_fully_replicated, f"{what} replicated, not split"
+        shapes = {s.data.shape for s in x.addressable_shards}
+        assert shapes == {sh.shard_shape(x.shape)} and \
+            sh.shard_shape(x.shape)[0] * n == x.shape[0], (what, shapes)
+    elif not sharded:
+        assert sh.is_fully_replicated, f"{what} not replicated: {sh}"
 
 
-def phase_mesh_keyed(app, args, devs):
+def _check_split(sh, n: int, what: str) -> None:
+    """A sharding that splits its array over all ``n`` devices."""
+    assert len(sh.device_set) == n and \
+        (n == 1 or not sh.is_fully_replicated), f"{what}: {sh}"
+
+
+def _compare(res, what: str) -> dict:
+    (mv, mm), (lv, lm) = res["mesh"], res["local"]
+    assert np.array_equal(mm, lm), f"{what}: mesh vs local validity differs"
+    assert np.array_equal(mv[mm], lv[lm]), \
+        f"{what}: mesh vs local values differ"
+    return {"flags": int(mm.sum()), "identical": True}
+
+
+def phase_mesh_keyed(app, sz: Sizes, seed: int, devs):
     """Keys sharded 4 ways (``placement=mesh``, ``keys='vmapped'``) vs the
-    same stream on one chip: every output bit-identical."""
+    same stream on one chip: every output bit-identical.  Outputs and
+    carried tails are split over the devices by key."""
     from jax.sharding import Mesh
     from repro.engine import ExecPolicy
     n = len(devs)
-    K, span = args.keys, args.seg * args.segs
-    value, valid = keyed_stream(app, K, span * args.chunks, args.seed,
+    K = sz.keys
+    value, valid = keyed_stream(app, K, sz.span * sz.chunks, seed,
                                 integer=True)
     mesh = Mesh(np.asarray(devs), ("data",))
     res = {}
     for name, placement in (("mesh", mesh), ("local", "local")):
         pol = ExecPolicy(body="sparse", keys="vmapped", placement=placement)
-        svc, compile_s = build(app, args, pol, K)
-        gv, gm, wall, n_dev = serve_chunks(svc, value, valid, span,
-                                           args.chunks)
-        if name == "mesh":  # outputs and carried tails spread over the mesh
-            assert n_dev == n, f"outputs on {n_dev} of {n} devices"
-            tv = svc.runner._tails["in"][0]
-            assert _sharded_over(tv, n), tv.sharding
+        svc, compile_s = build(app, sz, pol, K)
+        gv, gm, wall, out_sh = serve_chunks(svc, value, valid, sz.span,
+                                            sz.chunks)
+        if name == "mesh":
+            _check_split(out_sh, n, "outputs")
+            _check_spread(svc.runner._tails["in"][0], n, True, "value tail")
+            _check_spread(svc.runner._tails["in"][1], n, True, "valid tail")
         res[name] = (gv, gm)
         say(f"phase=mesh_keyed placement={name} keys={K} "
-            f"chunks={args.chunks} compile_s={compile_s!r} "
+            f"chunks={sz.chunks} compile_s={compile_s!r} "
             f"chunk_wall_s={wall!r}")
-    (mv, mm), (lv, lm) = res["mesh"], res["local"]
-    assert np.array_equal(mm, lm), "mesh vs local validity differs"
-    assert np.array_equal(mv[mm], lv[lm]), "mesh vs local values differ"
-    return {"flags": int(mm.sum()), "identical": True}
+    return _compare(res, "mesh keyed")
 
 
-def phase_mesh_single(args, devs, value, valid):
+def phase_mesh_single(sz: Sizes, devs, value, valid):
     """One stream, its segments sharded 4 ways (``keys='single'``): the
-    window spans shards, read from the replicated chunk buffer."""
+    window spans shards, read from the replicated chunk buffer.  Outputs
+    are split over the devices by segment; the carried tails, which every
+    shard's window reads, are a full copy on each device."""
     from jax.sharding import Mesh
     from repro.data.apps import make_app
     from repro.engine import ExecPolicy
-    app = make_app("fraud", win=args.win)
-    span = args.seg * args.segs
+    n = len(devs)
+    app = make_app("fraud", win=sz.win)
     mesh = Mesh(np.asarray(devs), ("data",))
     res = {}
     for name, placement in (("mesh", mesh), ("local", "local")):
         pol = ExecPolicy(body="sparse", keys="single", placement=placement)
-        svc, compile_s = build(app, args, pol, None)
-        gv, gm, _, n_dev = serve_chunks(svc, value, valid, span,
-                                        args.chunks, keyed=False)
+        svc, compile_s = build(app, sz, pol, None)
+        gv, gm, _, out_sh = serve_chunks(svc, value, valid, sz.span,
+                                         sz.chunks, keyed=False)
+        if name == "mesh":
+            _check_split(out_sh, n, "outputs")
+            _check_spread(svc.runner._tails["in"][0], n, False, "value tail")
+            _check_spread(svc.runner._tails["in"][1], n, False, "valid tail")
         res[name] = (gv, gm)
-        say(f"phase=mesh_single placement={name} chunks={args.chunks} "
-            f"compile_s={compile_s!r}")
-    (mv, mm), (lv, lm) = res["mesh"], res["local"]
-    assert np.array_equal(mm, lm), "mesh vs local validity differs"
-    assert np.array_equal(mv[mm], lv[lm]), "mesh vs local values differ"
-    return {"flags": int(mm.sum()), "identical": True}
+        say(f"phase=mesh_single placement={name} chunks={sz.chunks} "
+            f"compile_s={compile_s!r} out_sharding={out_sh}")
+    return _compare(res, "mesh single")
 
 
-def phase_halo(args, devs, value, valid):
+def phase_halo(sz: Sizes, devs, value, valid):
     """Time sharded 4 ways one-shot (``parallel.shard_map_run``): each
     shard's core is shorter than the window, so its left halo arrives
     over the multi-hop ppermute chain of ``core/halo.py``."""
@@ -352,12 +388,12 @@ def phase_halo(args, devs, value, valid):
     from repro.core.stream import SnapshotGrid
     from repro.data.apps import make_app
     n = len(devs)
-    app = make_app("fraud", win=args.win)
-    core = args.seg * args.segs // n
+    app = make_app("fraud", win=sz.win)
+    core = sz.span // n
     T = core * n
     exe = qc.compile_query(app.query.node, out_len=core)
     hops = -(-exe.input_specs["in"].left_halo // core)
-    assert hops >= 2, f"window {args.win} over {core}-tick cores: 1 hop"
+    assert hops >= 2, f"window {sz.win} over {core}-tick cores: 1 hop"
     g = {"in": SnapshotGrid(value=jnp.asarray(value[:T]),
                             valid=jnp.asarray(valid[:T]), t0=0, prec=1)}
     mesh = Mesh(np.asarray(devs), ("data",))
@@ -365,66 +401,63 @@ def phase_halo(args, devs, value, valid):
     sh = shard_map_run(exe, g, mesh, axis="data")
     sv, sm = np.asarray(sh.value), np.asarray(sh.valid)
     wall = time.perf_counter() - t0
-    assert _sharded_over(sh.value, n), sh.value.sharding
+    _check_spread(sh.value, n, True, "sharded output")
     loc = partition_run(exe, g, 0, n)
     lv, lm = np.asarray(loc.value), np.asarray(loc.valid)
     assert np.array_equal(sm, lm), "sharded vs local validity differs"
     assert np.array_equal(sv[sm], lv[lm]), "sharded vs local values differ"
-    say(f"phase=halo shards={n} core={core} win={args.win} hops={hops} "
+    say(f"phase=halo shards={n} core={core} win={sz.win} hops={hops} "
         f"wall_s={wall!r}")
     return {"flags": int(sm.sum()), "hops": hops, "identical": True}
 
 
 # -- drivers -------------------------------------------------------------------
 
-def reduced_line(args) -> str:
+def reduced_line(sz: Sizes, chips: int) -> str:
     """Every cut this run makes to the deployment's scale."""
-    span = args.seg * args.segs
-    if args.chips == 4:
+    if chips == 4:
         cuts = ["amounts rounded to integers <= 120 (exact float sums, so "
                 "mesh vs one chip is compared bit for bit)",
-                f"halo phase: one stream of {span} ticks, one shot"]
+                f"halo phase: one stream of {sz.span} ticks, one shot"]
     else:
-        cuts = [f"reference checked on {min(args.sample, args.keys)} of "
-                f"{args.keys} keys",
-                f"event path: {args.event_keys} keys x {args.event_chunks} "
-                f"chunks ({args.event_keys * span * args.event_chunks} "
+        cuts = [f"reference checked on {min(sz.sample, sz.keys)} of "
+                f"{sz.keys} keys",
+                f"event path: {sz.event_keys} keys x {sz.event_chunks} "
+                f"chunks ({sz.event_keys * sz.span * sz.event_chunks} "
                 f"ticks), python-rate per-event admission"]
-    return f"reduced: stream of {args.chunks} chunks x {span} ticks/key; " \
+    return f"reduced: stream of {sz.chunks} chunks x {sz.span} ticks/key; " \
         + "; ".join(cuts)
 
 
-def run_one(args) -> dict:
+def run_one(sz: Sizes, seed: int) -> dict:
     """One chip: the keyed deployment under both bodies, then the event
     path, each against the reference."""
-    app = fraud_app(args.win)
-    rng = np.random.default_rng(args.seed)
-    span = args.seg * args.segs
+    app = fraud_app(sz.win)
+    rng = np.random.default_rng(seed)
     t0 = time.perf_counter()
-    value, valid = keyed_stream(app, args.keys, span * args.chunks,
-                                args.seed)
-    sample = np.sort(rng.choice(args.keys, min(args.sample, args.keys),
+    value, valid = keyed_stream(app, sz.keys, sz.span * sz.chunks, seed)
+    sample = np.sort(rng.choice(sz.keys, min(sz.sample, sz.keys),
                                 replace=False))
-    ref = reference(app, value, valid, sample, span)
-    say(f"setup: keys={args.keys} ticks={value.shape[1]} "
-        f"chunk_bytes={value[:, :span].nbytes + valid[:, :span].nbytes} "
-        f"sample={len(sample)} data+reference_wall_s="
+    ref = reference(app, value, valid, sample, sz.span)
+    say(f"setup: keys={sz.keys} ticks={value.shape[1]} "
+        f"chunk_bytes={value[:, :sz.span].nbytes + valid[:, :sz.span].nbytes}"
+        f" sample={len(sample)} data+reference_wall_s="
         f"{time.perf_counter() - t0!r}")
-    out = {body: phase_keyed(app, args, body, value, valid, sample, ref)
+    out = {body: phase_keyed(app, sz, body, value, valid, sample, ref)
            for body in ("sparse", "dense")}
-    out["events"] = phase_events(app, args, rng)
+    out["events"] = phase_events(app, sz, seed, rng)
     return out
 
 
-def run_four(args, devs) -> dict:
+def run_four(sz: Sizes, seed: int, devs) -> dict:
     """Four chips: only the mesh placements and what they are compared
     with, bit for bit, on integer-valued amounts."""
-    app = fraud_app(args.win)
-    out = {"mesh_keyed": phase_mesh_keyed(app, args, devs)}
-    value, valid = keyed_stream(app, 1, args.seg * args.segs * args.chunks,
-                                args.seed + 2, integer=True)
-    out["mesh_single"] = phase_mesh_single(args, devs, value[0], valid[0])
-    out["halo"] = phase_halo(args, devs, value[0], valid[0])
+    app = fraud_app(sz.win)
+    out = {"mesh_keyed": phase_mesh_keyed(app, sz, seed, devs)}
+    value, valid = keyed_stream(app, 1, sz.span * sz.chunks, seed + 2,
+                                integer=True)
+    out["mesh_single"] = phase_mesh_single(sz, devs, value[0], valid[0])
+    out["halo"] = phase_halo(sz, devs, value[0], valid[0])
     return out
 
 
@@ -433,15 +466,18 @@ def main(argv=None) -> int:
     devs = require_tpu(args.chips)
     import jax
     d = devs[0]
+    sz = DEPLOYMENT
     say(f"device platform={d.platform} kind={d.device_kind} "
         f"count={len(devs)} jax={jax.__version__}")
     say("figures are smoke figures on the host clock, not device metrics")
-    say(reduced_line(args))
+    say(f"deployment: {dataclasses.asdict(sz)} late_frac={LATE_FRAC} "
+        f"seed={args.seed}")
+    say(reduced_line(sz, args.chips))
     t0 = time.perf_counter()
     if args.chips == 4:
-        run_four(args, devs[:4])
+        run_four(sz, args.seed, devs[:4])
     else:
-        run_one(args)
+        run_one(sz, args.seed)
     say(f"all phases passed; total_wall_s={time.perf_counter() - t0!r}")
     print(json.dumps({"ok": True, "device": {
         "platform": d.platform, "kind": d.device_kind,

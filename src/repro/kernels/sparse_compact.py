@@ -6,7 +6,8 @@ in this segment's dilated lineage change?*  The staged implementation
 (engine/runner phases, core/sparse one-shot) answers it in three jitted
 passes — per-source tick diff, `ChangePlan` dilation via cumsum range
 queries, per-segment reduction — materializing a full-length dirty mask
-between them.  This kernel fuses all three into a single ``pallas_call``:
+between them.  This kernel fuses all three into one ``pallas_call`` per
+channel matrix, the per-matrix segment flags ORed together:
 
 * Every source grid is flattened into per-dtype channel matrices ``(C, T)``
   (:func:`grid_mats`): value leaves become rows, the validity mask is cast

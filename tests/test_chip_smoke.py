@@ -12,9 +12,8 @@ sys.path.insert(0, ROOT)
 
 import chip_smoke  # noqa: E402
 
-TINY = ["--keys", "8", "--win", "50", "--seg", "32", "--segs", "4",
-        "--chunks", "3", "--sample", "4", "--event-keys", "4",
-        "--event-chunks", "2", "--lateness", "16"]
+TINY = chip_smoke.Sizes(keys=8, win=50, seg=32, segs=4, chunks=3, sample=4,
+                        event_keys=4, event_chunks=2, lateness=16)
 
 
 def test_chip_smoke_refuses_cpu():
@@ -27,11 +26,22 @@ def test_chip_smoke_refuses_cpu():
     assert "no TPU found" in p.stderr
 
 
+def test_chip_smoke_deployment_is_fixed():
+    """The command line selects chips and seed only; the served sizes are
+    the deployment's."""
+    assert chip_smoke.DEPLOYMENT == chip_smoke.Sizes(
+        keys=4096, win=1000, seg=256, segs=8, chunks=8, sample=64,
+        event_keys=64, event_chunks=3, lateness=64)
+    assert chip_smoke.DEPLOYMENT.span == 2048
+    with pytest.raises(SystemExit):
+        chip_smoke.parse_args(["--keys", "8"])
+
+
 def test_chip_smoke_one_chip_phases(monkeypatch):
     """Keyed deployment under both bodies plus the event path, each
     checked against the eventspe reference."""
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    out = chip_smoke.run_one(chip_smoke.parse_args(TINY))
+    out = chip_smoke.run_one(TINY, seed=0)
     assert set(out) == {"sparse", "dense", "events"}
     assert out["sparse"]["ref_flags"] > 0
     assert out["sparse"] == out["dense"]
@@ -42,12 +52,10 @@ def test_chip_smoke_mesh_phases(monkeypatch):
     """The four-chip phases on the devices this process has: mesh
     placements and the multi-hop halo, bit for bit against local runs."""
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    args = chip_smoke.parse_args(
-        ["--chips", "4", "--keys", "8", "--win", "150", "--seg", "16",
-         "--segs", "4", "--chunks", "3"])
+    sz = chip_smoke.Sizes(keys=8, win=150, seg=16, segs=4, chunks=3)
     devs = jax.devices()[:4]
     if 4 % len(devs):
         pytest.skip(f"{len(devs)} devices do not divide the segment count")
-    out = chip_smoke.run_four(args, devs)
+    out = chip_smoke.run_four(sz, 0, devs)
     assert all(r["identical"] for r in out.values())
     assert out["halo"]["hops"] >= 2

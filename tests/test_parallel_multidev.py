@@ -326,9 +326,8 @@ def test_served_mesh_placements_bit_identical_on_4_devices():
         sys.path.insert(0, ".")
         import jax
         import chip_smoke as cs
-        args = cs.parse_args(["--chips", "4", "--keys", "8", "--win", "50",
-                              "--seg", "32", "--segs", "4", "--chunks", "3"])
-        res = cs.run_four(args, jax.devices()[:4])
+        sz = cs.Sizes(keys=8, win=50, seg=32, segs=4, chunks=3)
+        res = cs.run_four(sz, 0, jax.devices()[:4])
         assert all(r["identical"] for r in res.values()), res
         assert res["halo"]["hops"] == 2, res
         print("MESH_OK")
