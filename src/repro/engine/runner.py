@@ -61,7 +61,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-import time
 
 from ..core import ir
 from ..core import sparse as sparse_mod
@@ -262,6 +261,10 @@ class Runner:
         self._m_donated = m.counter(
             "runner.donated_steps",
             "steps run through a buffer-donating jitted step", "steps")
+        self._m_dispatches = m.counter(
+            "runner.dispatches",
+            "device programs launched (chunk step, metrics accumulator, "
+            "revision steps)", "programs")
         self._m_lat = m.histogram(
             "runner.step_seconds", log_buckets(1e-5, 10.0, per_decade=3),
             "per-chunk step wall time (dispatch, not device completion)",
@@ -382,7 +385,7 @@ class Runner:
                     frac.at[fi].add(1))
 
         self.metrics.tracer.record_compile(self._compile_label(key))
-        return self._stage(key, accum, donate=(0,))
+        return self._stage(key, accum, "tilt_obs_accum", donate=(0,))
 
     def _obs_sparse_chunk(self, seg_dirty) -> None:
         """Per-sparse-chunk device metric update: one jitted accumulator
@@ -392,7 +395,9 @@ class Runner:
                             jnp.zeros((len(self._obs_caps),), jnp.int32),
                             jnp.zeros((len(self._obs_frac_edges) + 1,),
                                       jnp.int32))
-        self._mstate = self._obs_accum()(self._mstate, seg_dirty)
+        with self.metrics.tracer.span("runner.obs_accum"):
+            self._mstate = self._obs_accum()(self._mstate, seg_dirty)
+        self._m_dispatches.add(1)
         total, picks, frac = self._mstate
         self._m_dirty.set_device(total)
         self._m_picks.set_device(picks)
@@ -448,11 +453,14 @@ class Runner:
         dofs = self.staging_key_dofs()
         return (kind,) + tuple(dofs[k] for k in self._KEY_DOFS) + extra
 
-    def _stage(self, key, fn, donate=()):
-        """Jit + cache one staged step; the raw traced fn and its donation
-        contract stay inspectable at ``("raw",) + key`` for the static
-        auditor (repro.analysis), which re-traces them under
-        ``jax.make_jaxpr`` instead of guessing from the compiled form."""
+    def _stage(self, key, fn, name: str, donate=()):
+        """Jit + cache one staged step under a stable program name (the
+        compiled module reads ``jit_<name>`` in a device trace); the raw
+        traced fn and its donation contract stay inspectable at
+        ``("raw",) + key`` for the static auditor (repro.analysis), which
+        re-traces them under ``jax.make_jaxpr`` instead of guessing from
+        the compiled form."""
+        fn.__name__ = name
         cache = self.spec.step_cache
         cache[("raw",) + key] = (fn, tuple(donate))
         cache[key] = (jax.jit(fn, donate_argnums=tuple(donate))
@@ -579,7 +587,8 @@ class Runner:
                     (U, L) + x.shape[2:]), fv)
                 gm = jnp.take(fm, idx, axis=1).reshape(U, L)
                 units.append((gv, gm))
-            outs = sharded(jnp.ones((U,), bool), *units)
+            with jax.named_scope("tilt.compute"):
+                outs = sharded(jnp.ones((U,), bool), *units)
             outs = {o: (_tm(lambda x: x.reshape(
                         (K, n_segs * x.shape[1]) + x.shape[2:]), ov),
                         om.reshape(K, -1))
@@ -598,7 +607,7 @@ class Runner:
         # the carried tails are runner-owned (step outputs, or zeros /
         # restore-copies) — donate them so steady-state chunks update the
         # halo buffers in place instead of reallocating
-        return self._stage(key, step, donate=(0,))
+        return self._stage(key, step, "tilt_dense_step", donate=(0,))
 
     # -- sparse body (one fused jitted step per chunk) -----------------------
     #
@@ -632,41 +641,50 @@ class Runner:
 
         full_cap = cap == U_loc
 
+        def one(*f):
+            return outs_fn(dict(zip(names, f)))
+
+        # the phases are named scopes (``tilt.*`` in each op's metadata, so
+        # a device trace attributes op time to them); they sit in here so
+        # every branch of the capacity ladder carries them
         def local(w, *flat):
-            if full_cap:
-                # full-capacity bucket (count > U_loc/2): compaction saves
-                # nothing, so compute every unit in place — static ids, no
-                # nonzero, identity scatter.  Bit-identical: computing a
-                # clean unit yields exactly its hold value (the sparse
-                # exactness contract), and the hold fill downstream still
-                # overwrites clean units from the dirty chain.
-                ids = jnp.arange(cap)
-            else:
-                ids = jnp.nonzero(w, size=cap, fill_value=0)[0]
-            if keyed:
-                k_ids, s_ids = ids // n_segs, ids % n_segs
-            else:
-                base = (jax.lax.axis_index(axis) * U_loc
-                        if mesh is not None else 0)
-                k_ids, s_ids = jnp.zeros_like(ids), ids + base
-            gath = []
-            for name, (bv, bm) in zip(names, flat):
-                s = specs[name]
-                tidx = s_ids[:, None] * s.core + jnp.arange(s.length)[None, :]
-                gath.append((
-                    _tm(lambda x: x[k_ids[:, None], tidx], bv),
-                    bm[k_ids[:, None], tidx]))
-
-            def one(*f):
-                return outs_fn(dict(zip(names, f)))
-
-            outs = jax.vmap(one)(*gath)                  # {o: (cap, S_o, …)}
+            with jax.named_scope("tilt.compact"):
+                if full_cap:
+                    # full-capacity bucket (count > U_loc/2): compaction
+                    # saves nothing, so compute every unit in place —
+                    # static ids, no nonzero, identity scatter.
+                    # Bit-identical: computing a clean unit yields exactly
+                    # its hold value (the sparse exactness contract), and
+                    # the hold fill downstream still overwrites clean units
+                    # from the dirty chain.
+                    ids = jnp.arange(cap)
+                else:
+                    ids = jnp.nonzero(w, size=cap, fill_value=0)[0]
+                if keyed:
+                    k_ids, s_ids = ids // n_segs, ids % n_segs
+                else:
+                    base = (jax.lax.axis_index(axis) * U_loc
+                            if mesh is not None else 0)
+                    k_ids, s_ids = jnp.zeros_like(ids), ids + base
+                pos = (None if full_cap
+                       else jnp.clip(jnp.cumsum(w) - 1, 0, cap - 1))
+            with jax.named_scope("tilt.gather"):
+                gath = []
+                for name, (bv, bm) in zip(names, flat):
+                    s = specs[name]
+                    tidx = (s_ids[:, None] * s.core
+                            + jnp.arange(s.length)[None, :])
+                    gath.append((
+                        _tm(lambda x: x[k_ids[:, None], tidx], bv),
+                        bm[k_ids[:, None], tidx]))
+            with jax.named_scope("tilt.compute"):
+                outs = jax.vmap(one)(*gath)              # {o: (cap, S_o, …)}
             if full_cap:
                 return outs
-            pos = jnp.clip(jnp.cumsum(w) - 1, 0, cap - 1)
-            return {o: (_tm(lambda x: jnp.take(x, pos, axis=0), ov),
-                        jnp.take(om, pos, axis=0))
-                    for o, (ov, om) in outs.items()}     # {o: (U_loc, S_o, …)}
+            with jax.named_scope("tilt.scatter"):
+                return {o: (_tm(lambda x: jnp.take(x, pos, axis=0), ov),
+                            jnp.take(om, pos, axis=0))
+                        for o, (ov, om) in outs.items()}  # (U_loc, S_o, …)
 
         cache[key] = local
         return cache[key]
@@ -753,8 +771,9 @@ class Runner:
         hold = self._hold_local()
 
         def switched(w, *flat):
-            cnt = jnp.sum(w.astype(jnp.int32))
-            b = jnp.searchsorted(jnp.asarray(caps), cnt, side="left")
+            with jax.named_scope("tilt.compact"):
+                cnt = jnp.sum(w.astype(jnp.int32))
+                b = jnp.searchsorted(jnp.asarray(caps), cnt, side="left")
             return jax.lax.switch(b, branches, w, *flat)
 
         sharded = self._shard_body(switched, len(names))
@@ -778,7 +797,10 @@ class Runner:
                 nd = nd | neq
             return nd
 
-        def step(tails, dirty, prev, seeds, chunks):
+        def detect(tails, dirty, prev, chunks):
+            """Change detection: each input's buffer (carried tail +
+            chunk), its per-segment dirty flags, and the carried tails,
+            dirty tails and snapshots of the next chunk."""
             bufs, new_tails, new_dirty, new_prev = {}, {}, {}, {}
             seg_dirty = jnp.zeros((K, n_segs), bool)
             for name in names:
@@ -830,16 +852,25 @@ class Runner:
             if force_first:
                 seg_dirty = seg_dirty.at[:, 0].set(True)
             seg_dirty = self._unit_flags(seg_dirty)
+            return bufs, new_tails, new_dirty, new_prev, seg_dirty
+
+        def step(tails, dirty, prev, seeds, chunks):
+            with jax.named_scope("tilt.change_detect"):
+                bufs, new_tails, new_dirty, new_prev, seg_dirty = detect(
+                    tails, dirty, prev, chunks)
             full = sharded(seg_dirty.reshape(U),
                            *[bufs[nm] for nm in names])
             full = {o: (_tm(lambda x: x.reshape(
                             (K, n_segs) + x.shape[1:]), fv),
                         fm.reshape((K, n_segs) + fm.shape[1:]))
                     for o, (fv, fm) in full.items()}
-            outs, new_seeds = hold(full, seg_dirty, seeds)
+            with jax.named_scope("tilt.hold"):
+                outs, new_seeds = hold(full, seg_dirty, seeds)
             return outs, new_tails, new_dirty, new_prev, new_seeds, seg_dirty
 
         return self._stage(key, step,
+                           "tilt_sparse_first" if force_first
+                           else "tilt_sparse_steady",
                            donate=() if force_first else (0, 1, 2, 3))
 
     def _zero_seeds(self, chunk_in):
@@ -872,12 +903,14 @@ class Runner:
             seeds.update(st["seed"])
         else:
             seeds = st["seed"]
-        outs, new_tails, new_dirty, new_prev, new_seeds, seg_dirty = \
-            self._fused_sparse_step(force_first)(
-                self._tails, st["dirty"], st["prev"], seeds, chunk_in)
+        step = self._fused_sparse_step(force_first)
+        with self.metrics.tracer.span("runner.dispatch"):
+            outs, new_tails, new_dirty, new_prev, new_seeds, seg_dirty = \
+                step(self._tails, st["dirty"], st["prev"], seeds, chunk_in)
         # device-resident diagnostics: no transfer, no dispatch stall
         self.last_seg_dirty = seg_dirty
         if self.metrics.on:
+            self._m_dispatches.add(1)
             self._obs_sparse_chunk(seg_dirty)
             if not force_first:
                 self._m_donated.add(1)
@@ -1109,43 +1142,51 @@ class Runner:
         state commits only after the step succeeded, so a raise leaves the
         runner exactly as it was.
         """
-        t0 = time.perf_counter()
-        snap = None
-        if self._rev_ring is not None:
-            # pre-chunk state snapshot for the revision ring: captured
-            # before dispatch (the donating step consumes the tails), as a
-            # host pytree — one device sync per chunk, the documented cost
-            # of revisability (docs/architecture.md "Out-of-order
-            # ingestion"); hot paths that never see late data leave the
-            # ring disabled and keep the zero-sync steady state
-            snap = {"chunk": self._t // (self.n_segs * self.spec.span),
-                    "state": self.state()}
-        chunk_in = self._ingest(chunks)
-        self._init_missing_tails(chunk_in)
-        if self.policy.sparse:
-            outs, commit = self._sparse_chunk(chunk_in)
-        else:
-            outs, new_tails = self._dense_step()(self._tails, chunk_in)
-            if self.metrics.on and self.spec.jit:
-                self._m_donated.add(1)
+        tracer = self.metrics.tracer
+        with tracer.span("runner.step") as took:
+            snap = None
+            if self._rev_ring is not None:
+                # pre-chunk state snapshot for the revision ring: captured
+                # before dispatch (the donating step consumes the tails),
+                # as a host pytree — one device sync per chunk, the
+                # documented cost of revisability (docs/architecture.md
+                # "Out-of-order ingestion"); hot paths that never see late
+                # data leave the ring disabled and keep the zero-sync
+                # steady state
+                snap = {"chunk": self._t // (self.n_segs * self.spec.span),
+                        "state": self.state()}
+            with tracer.span("runner.ingest"):
+                chunk_in = self._ingest(chunks)
+                self._init_missing_tails(chunk_in)
+            if self.policy.sparse:
+                outs, commit = self._sparse_chunk(chunk_in)
+            else:
+                step = self._dense_step()
+                with tracer.span("runner.dispatch"):
+                    outs, new_tails = step(self._tails, chunk_in)
+                if self.metrics.on:
+                    self._m_dispatches.add(1)
+                    if self.spec.jit:
+                        self._m_donated.add(1)
 
-            def commit(new_tails=new_tails):
-                self._tails = new_tails
+                def commit(new_tails=new_tails):
+                    self._tails = new_tails
 
-        result = {}
-        for o, (v, m) in self._postprocess(outs).items():
-            result[o] = SnapshotGrid(value=v, valid=m, t0=self._t,
-                                     prec=self.spec.out_precs[o])
-        commit()
-        if snap is not None:
-            self._rev_ring.append(snap)
-        self._t += self.n_segs * self.spec.span
+            with tracer.span("runner.commit"):
+                result = {}
+                for o, (v, m) in self._postprocess(outs).items():
+                    result[o] = SnapshotGrid(value=v, valid=m, t0=self._t,
+                                             prec=self.spec.out_precs[o])
+                commit()
+                if snap is not None:
+                    self._rev_ring.append(snap)
+                self._t += self.n_segs * self.spec.span
         if self.metrics.on:
-            # host-side arithmetic only (perf_counter + numpy bisect):
-            # wall time around the async dispatch, never a device read
+            # host-side arithmetic only: the span's wall time around the
+            # async dispatch, never a device read
             self._m_chunks.add(1)
             self._m_units.add(self._U)
-            self._m_lat.observe(time.perf_counter() - t0)
+            self._m_lat.observe(took.seconds)
         return result["__out"] if self.spec.solo else result
 
     def run(self, inputs: Dict[str, SnapshotGrid], n_chunks: int):
@@ -1427,8 +1468,9 @@ class Runner:
         caps = np.asarray(ladder, np.int32)
 
         def switched(w, *flat):
-            cnt = jnp.sum(w.astype(jnp.int32))
-            b = jnp.searchsorted(jnp.asarray(caps), cnt, side="left")
+            with jax.named_scope("tilt.compact"):
+                cnt = jnp.sum(w.astype(jnp.int32))
+                b = jnp.searchsorted(jnp.asarray(caps), cnt, side="left")
             return jax.lax.switch(b, branches, w, *flat)
 
         sharded = self._shard_body(switched, len(names))
@@ -1456,7 +1498,7 @@ class Runner:
 
         # the walked-forward tails are revision-owned (ring-entry copies,
         # then step outputs) — donate them like the chunk steps do
-        return self._stage(key, step, donate=(0,))
+        return self._stage(key, step, "tilt_revision_step", donate=(0,))
 
     def revise(self, from_chunk: int, chunks, seg_dirty, *,
                commit: bool = True):
@@ -1537,6 +1579,8 @@ class Runner:
             n_units += int(sd.sum())
             outs, tails = step(tails, chunk_in,
                                self._unit_flags(jnp.asarray(sd)))
+            if self.metrics.on:
+                self._m_dispatches.add(1)
             last_in, last_sd, last_outs = chunk_in, sd, outs
             res = {}
             for o, (v, m) in self._postprocess(outs).items():

@@ -145,6 +145,7 @@ def _seg_dirty_one(x, geom, n_segs: int, interpret: bool):
         out_specs=pl.BlockSpec((None, G, 1), lambda g: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_groups, G, 1), jnp.int32),
         interpret=interpret,
+        name="seg_dirty",   # the custom call's name in a device trace
     )(*([xp] * NB))
     return out.reshape(n_groups * G)[:n_segs] > 0
 

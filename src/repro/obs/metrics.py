@@ -337,7 +337,7 @@ class Metrics:
         self._collectors: Dict[str, Callable[[], None]] = {}
         self._warmup_hooks: Dict[str, Callable[[], None]] = {}
         from .trace import Tracer
-        self.tracer = Tracer()
+        self.tracer = Tracer(on=lambda: self.on)
 
     @property
     def on(self) -> bool:

@@ -1,12 +1,20 @@
 """Phase tracing: wall-time span trees and a recompile detector.
 
 Spans answer "where does the wall time go" at phase granularity —
-plan / compile / execute / refit — without a profiler run.  ``span()``
-is a context manager; nesting builds slash-separated paths
-(``session.rebuild/plan``), and each path aggregates count / total / max
-seconds.  This is *host* wall time around dispatch boundaries: spans
-never touch device values, so they are safe anywhere, including around
-the transfer-guarded hot path.
+serve / ingest / dispatch / commit, plan / compile / refit — without a
+profiler run.  ``span()`` is a context manager; nesting builds
+slash-separated paths (``serve.call/runner.step``), and each path
+aggregates count / total / max seconds.  This is *host* wall time around
+dispatch boundaries: spans never touch device values, so they are safe
+anywhere, including around the transfer-guarded hot path.
+
+Every span also opens a ``jax.profiler.TraceAnnotation`` named by its
+path, with the span's keyword ids (``chunk=7``) as annotation metadata.
+It records nothing unless a profiler session is active; under one, the
+spans land on the host plane of the trace, on the profiler's clock, next
+to the device's ops.  Under :func:`repro.obs.disabled` or
+``Metrics(enabled=False)`` a span is a no-op: no clock read, no
+annotation, no aggregate.
 
 The recompile detector rides the engine's own staging discipline: every
 jit-cache miss in ``Runner``'s ``step_cache`` (one entry per (policy,
@@ -16,48 +24,59 @@ rebuilt — an unexpected retrace; :meth:`Tracer.retraces` surfaces
 exactly those.  The runner additionally cross-checks jax's own cache via
 ``jitted._cache_size()`` at snapshot time (``runner.jit_entries`` gauge),
 which catches shape-driven retraces *inside* one staged step.
-
-Optional passthrough: with ``REPRO_OBS_JAX_TRACE=1``, spans also open
-``jax.profiler.TraceAnnotation`` so they appear on the TensorBoard /
-Perfetto timeline when a profiler trace is active.
 """
 from __future__ import annotations
 
 import contextlib
-import os
 import time
-from typing import Dict, List
+from typing import Callable, Dict, List, Optional
+
+import jax
+
+from .metrics import _on
 
 __all__ = ["Tracer"]
 
 
-def _jax_annotation(name: str):
-    if os.environ.get("REPRO_OBS_JAX_TRACE", "0") != "1":
-        return contextlib.nullcontext()
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+class SpanTime:
+    """What a span measured: its wall seconds, set when it closes (0.0
+    for a span that did not record)."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self):
+        self.seconds = 0.0
 
 
 class Tracer:
     """Aggregating span recorder + per-key compile counter."""
 
-    def __init__(self):
+    def __init__(self, on: Optional[Callable[[], bool]] = None):
+        # whether spans record: the owning registry's switch, else the
+        # module-wide one (obs.disabled())
+        self._on = on if on is not None else _on
         self._stack: List[str] = []
         self._spans: Dict[str, Dict] = {}
         self._compiles: Dict[str, int] = {}
         self._aot: Dict[str, str] = {}
 
     @contextlib.contextmanager
-    def span(self, name: str):
-        """Time a phase.  Nested spans build ``outer/inner`` paths."""
+    def span(self, name: str, **ids):
+        """Time a phase.  Nested spans build ``outer/inner`` paths; the
+        profiler annotation carries ``ids`` (e.g. ``chunk=7``).  Yields a
+        :class:`SpanTime` whose ``seconds`` is set on exit."""
+        took = SpanTime()
+        if not self._on():
+            yield took
+            return
         path = "/".join(self._stack + [name])
         self._stack.append(name)
         t0 = time.perf_counter()
         try:
-            with _jax_annotation(path):
-                yield
+            with jax.profiler.TraceAnnotation(path, **ids):
+                yield took
         finally:
-            dt = time.perf_counter() - t0
+            took.seconds = dt = time.perf_counter() - t0
             self._stack.pop()
             s = self._spans.setdefault(
                 path, {"count": 0, "total_s": 0.0, "max_s": 0.0})

@@ -12,11 +12,11 @@ real chunk is a cache hit: no tracing, no compile, no retrace recorded.
 Persistence uses ``jax.experimental.serialize_executable``: each compiled
 step serializes to ``(payload, in_tree, out_tree)`` (all picklable) keyed
 by a structural fingerprint over everything the executable depends on —
-query IR fingerprints, geometry, policy point, metrics mode, backend and
-jax version.  A fresh process with a warm :class:`ExecutableCache` (plus
-a persisted plan artifact for the seed shapes — see
-:mod:`repro.multiquery.shared`) reaches first-result without tracing,
-planning or compiling anything.
+query IR fingerprints, geometry, policy point, metrics mode, backend, jax
+version and the version of the code the steps are staged from.  A fresh
+process with a warm :class:`ExecutableCache` (plus a persisted plan
+artifact for the seed shapes — see :mod:`repro.multiquery.shared`)
+reaches first-result without tracing, planning or compiling anything.
 
 The complementary :func:`enable_jax_compilation_cache` turns on jax's own
 persistent compilation cache (HLO-hash keyed): it does not skip tracing,
@@ -28,6 +28,7 @@ cache is best-effort — a corrupt or stale entry degrades to a compile.
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import os
 import pathlib
@@ -40,7 +41,10 @@ import jax
 from ..core import ir
 
 __all__ = ["ExecutableCache", "aot_compile", "enable_jax_compilation_cache",
-           "step_fingerprint"]
+           "staged_code_version", "step_fingerprint"]
+
+# the packages whose code is traced into the staged steps
+_STAGED_PACKAGES = ("core", "engine", "kernels")
 
 
 # the checkout's fixed compile-cache path: resolved from this file, never
@@ -85,6 +89,27 @@ def _persistent_cache_off():
         cc.reset_cache()
 
 
+def source_version(root, packages) -> str:
+    """Hash of every ``.py`` file under ``root/<package>`` for each
+    package, by relative path and content."""
+    root = pathlib.Path(root)
+    h = hashlib.sha256()
+    for pkg in packages:
+        for f in sorted((root / pkg).rglob("*.py")):
+            h.update(f.relative_to(root).as_posix().encode() + b"\0")
+            h.update(f.read_bytes())
+    return h.hexdigest()
+
+
+@functools.lru_cache(maxsize=None)
+def staged_code_version() -> str:
+    """Version of the code the staged steps are traced from (the
+    ``core/``, ``engine/`` and ``kernels/`` sources), read once per
+    process: an executable persisted by other code never loads."""
+    return source_version(pathlib.Path(__file__).resolve().parents[1],
+                          _STAGED_PACKAGES)
+
+
 def _backend_tag() -> tuple:
     devs = jax.devices()
     return (jax.__version__, devs[0].platform, len(devs),
@@ -95,9 +120,10 @@ def step_fingerprint(runner, label: str, *,
                      query_fp: Optional[str] = None) -> str:
     """Process-stable content key of one staged step's executable: the
     query structure, the execution geometry (the staging-key DOFs with the
-    mesh reduced to its shape), the metrics mode and the backend.  Two
-    processes that would compile byte-equivalent steps agree on it; any
-    drift (new jax, different device count, changed geometry) misses."""
+    mesh reduced to its shape), the metrics mode, the backend and the
+    staged code's version.  Two processes that would compile
+    byte-equivalent steps agree on it; any drift (new jax, different
+    device count, changed geometry, an edited step body) misses."""
     spec = runner.spec
     if query_fp is None:
         if spec.roots:
@@ -113,7 +139,8 @@ def step_fingerprint(runner, label: str, *,
                     p.body, p.keys, p.dag,
                     p.axis if p.mesh is not None else None, p.n_shards,
                     runner.n_keys, runner.n_segs, runner.metrics.on,
-                    runner.revision_horizon, _backend_tag()))
+                    runner.revision_horizon, _backend_tag(),
+                    staged_code_version()))
     return hashlib.sha256(payload.encode()).hexdigest()
 
 

@@ -104,6 +104,8 @@ class ServeLoop:
                         else (device if device is not None
                               else jax.devices()[0]))
         m = self.metrics = runner.metrics
+        self._tracer = m.tracer
+        self._seq = 0  # chunk sequence number: the spans' ``chunk`` id
         self._m_call = m.histogram(
             "serve.call_seconds", log_buckets(1e-5, 10.0, per_decade=3),
             "end-to-end per-call serving latency (dispatch + device "
@@ -131,22 +133,43 @@ class ServeLoop:
         return self.aot_report
 
     # -- chunk path ----------------------------------------------------------
-    def _put(self, chunks: Dict[str, SnapshotGrid]) -> Dict[str, SnapshotGrid]:
+    def _put(self, chunks: Dict[str, SnapshotGrid], **ids
+             ) -> Dict[str, SnapshotGrid]:
         """Commit one request's grids to the serving device — an explicit
         (transfer-guard-legal) non-blocking H2D; issued for chunk k+1
-        before chunk k's compute dispatch so the transfer overlaps."""
+        before chunk k's compute dispatch so the transfer overlaps.  The
+        ``serve.put`` span times the host's enqueue, not the transfer."""
         if self._device is None:
             return chunks
         d = self._device
-        return {name: SnapshotGrid(
-                    value=_tm(lambda x: jax.device_put(x, d), g.value),
-                    valid=jax.device_put(g.valid, d), t0=g.t0, prec=g.prec)
-                for name, g in chunks.items()}
+        with self._tracer.span("serve.put", **ids):
+            return {name: SnapshotGrid(
+                        value=_tm(lambda x: jax.device_put(x, d), g.value),
+                        valid=jax.device_put(g.valid, d), t0=g.t0,
+                        prec=g.prec)
+                    for name, g in chunks.items()}
 
-    @staticmethod
-    def _block(out):
-        for g in (out.values() if isinstance(out, dict) else (out,)):
-            jax.block_until_ready(g.valid)
+    def _put_next(self, chunks: Dict[str, SnapshotGrid]) -> tuple:
+        """``(chunk id, staged grids)``: the put of the next chunk of the
+        chunk path, numbered in the order chunks are put."""
+        seq, self._seq = self._seq, self._seq + 1
+        return seq, self._put(chunks, chunk=seq)
+
+    def _call(self, seq: int, staged, block: bool):
+        """One chunk's step (and, with ``block``, the wait for its result)
+        under the ``serve.call`` span, whose duration is the
+        ``serve.call_seconds`` observation."""
+        with self._tracer.span("serve.call", chunk=seq) as took:
+            out = self.runner.step(staged)
+            if block:
+                self._block(out)
+        self._observe(took.seconds)
+        return out
+
+    def _block(self, out):
+        with self._tracer.span("serve.block"):
+            for g in (out.values() if isinstance(out, dict) else (out,)):
+                jax.block_until_ready(g.valid)
         return out
 
     def _observe(self, dt: float) -> None:
@@ -158,13 +181,7 @@ class ServeLoop:
 
     def step(self, chunks: Dict[str, SnapshotGrid], *, block: bool = True):
         """Serve one chunk (single-shot path: no lookahead to overlap)."""
-        staged = self._put(chunks)
-        t0 = time.perf_counter()
-        out = self.runner.step(staged)
-        if block:
-            self._block(out)
-        self._observe(time.perf_counter() - t0)
-        return out
+        return self._call(*self._put_next(chunks), block)
 
     def serve(self, chunk_source: Iterable[Dict[str, SnapshotGrid]], *,
               block: bool = True):
@@ -176,21 +193,16 @@ class ServeLoop:
         dispatch-deep and the caller owns synchronization."""
         it = iter(chunk_source)
         try:
-            cur = self._put(next(it))
+            cur = self._put_next(next(it))
         except StopIteration:
             return
         live = True
         while live:
             try:
-                nxt = self._put(next(it))  # k+1's H2D overlaps k's compute
+                nxt = self._put_next(next(it))  # k+1's H2D overlaps k's
             except StopIteration:
                 nxt, live = None, False
-            t0 = time.perf_counter()
-            out = self.runner.step(cur)
-            if block:
-                self._block(out)
-            self._observe(time.perf_counter() - t0)
-            yield out
+            yield self._call(*cur, block)
             cur = nxt
 
     # -- event path ----------------------------------------------------------

@@ -165,6 +165,45 @@ def test_warm_start_survives_missing_executable(tmp_path):
     assert np.asarray(out.valid).shape == (SPAN,)
 
 
+def test_source_version_follows_every_staged_source(tmp_path):
+    """The staged-code version hashes each package's sources by path and
+    content: an edit, a new file or a rename moves it."""
+    from repro.serve.aot import source_version
+    pkg = tmp_path / "engine"
+    pkg.mkdir()
+    (pkg / "runner.py").write_text("def step(x):\n    return x\n")
+    v0 = source_version(tmp_path, ["engine"])
+    assert source_version(tmp_path, ["engine"]) == v0
+    (pkg / "runner.py").write_text("def step(x):\n    return x + 1\n")
+    v1 = source_version(tmp_path, ["engine"])
+    (pkg / "extra.py").write_text("")
+    v2 = source_version(tmp_path, ["engine"])
+    (pkg / "extra.py").rename(pkg / "other.py")
+    v3 = source_version(tmp_path, ["engine"])
+    assert len({v0, v1, v2, v3}) == 4
+
+
+def test_executable_from_other_staged_code_misses(tmp_path, monkeypatch):
+    """An executable persisted by other step code is never loaded: the
+    fingerprint carries the staged code's version, so a warm cache
+    written before an edit to a step body demotes to the cold path and
+    recompiles."""
+    from repro.serve import aot
+    assert aot.staged_code_version() == aot.staged_code_version()
+    cache = str(tmp_path / "svc")
+    svc1 = build_service(_query(), out_len=SEG, segs_per_chunk=SPC,
+                         cache_dir=cache)
+    fp1 = aot.step_fingerprint(svc1.runner, "sparse_fused(steady)",
+                               query_fp=svc1.query_fp)
+    monkeypatch.setattr(aot, "staged_code_version", lambda: "edited")
+    assert aot.step_fingerprint(svc1.runner, "sparse_fused(steady)",
+                                query_fp=svc1.query_fp) != fp1
+    svc2 = build_service(_query(), out_len=SEG, segs_per_chunk=SPC,
+                         cache_dir=cache)
+    assert svc2.plan_source == "cold"
+    assert set(svc2.aot_report.values()) == {"compiled"}
+
+
 def test_plan_artifact_persists_across_cache_instances(tmp_path):
     from repro.core import ir
     from repro.multiquery import SharedPlanCache

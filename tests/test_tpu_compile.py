@@ -120,3 +120,57 @@ def test_prefix_scan_compiles_for_v5e(one_chip, C, T, units):
     shape = (C, T) if units is None else (units, C, T)
     g = f if units is None else jax.vmap(f)
     assert _compiled_has_kernel(g, one_chip, (shape, jnp.float32))
+
+
+# the phase scopes the fused sparse step names, and the dense step's one
+SPARSE_SCOPES = {"tilt.change_detect", "tilt.compact", "tilt.gather",
+                 "tilt.compute", "tilt.scatter", "tilt.hold"}
+STEP_CASES = [
+    ("fraud", "sparse", {"sparse_fused(first)": "tilt_sparse_first",
+                         "sparse_fused(steady)": "tilt_sparse_steady",
+                         "obs_accum": "tilt_obs_accum"}),
+    ("trend", "dense", {"dense": "tilt_dense_step"})]
+
+
+@pytest.mark.parametrize("app,body,names", STEP_CASES)
+def test_staged_steps_carry_names_and_scopes_on_v5e(one_chip, monkeypatch,
+                                                    app, body, names):
+    """The served runner's staged steps, compiled for the described chip
+    with the Pallas kernels in: each module is ``jit_<stable name>``, the
+    phase scopes reach the ops' ``op_name`` metadata (what a device trace
+    reads as ``tf_op``), the change-detection kernel is the custom call
+    ``seg_dirty`` and the window kernels keep their ``jit_sliding_*``
+    names."""
+    import re
+
+    from repro.engine import ExecPolicy, Runner
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    args = {"win": 1000} if app == "fraud" else {}
+    exe = qc.compile_query(make_keyed_app(app, **args).query.node,
+                           out_len=256, sparse=body == "sparse")
+    runner = Runner(exe, ExecPolicy(body=body, keys="vmapped"), n_keys=8,
+                    segs_per_chunk=4)
+    steps = runner.staged_steps()
+    assert {s["label"] for s in steps} == set(names)
+    for step in steps:
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), step["args"])
+        text = step["fn"].lower(*shapes).compile().as_text()
+        assert text.startswith(f"HloModule jit_{names[step['label']]},")
+        scopes = set(re.findall(r'op_name="[^"]*?(tilt\.[a-z_]+)', text))
+        kernels = re.findall(r"(%\S+) = .*custom_call_target="
+                             r'"tpu_custom_call"', text)
+        if step["label"] == "obs_accum":
+            assert not kernels
+            continue
+        window = [k for k in kernels if "seg_dirty" not in k]
+        assert window and all(re.search(r"jit_sliding_(sum|assoc)", k)
+                              for k in window), kernels
+        if body == "dense":
+            assert scopes == {"tilt.compute"}
+        else:
+            assert scopes == SPARSE_SCOPES
+            assert [k for k in kernels if "seg_dirty" in k], kernels
