@@ -135,6 +135,46 @@ def _bc(mask, x):
     return mask.reshape(mask.shape + (1,) * (x.ndim - mask.ndim))
 
 
+def _unit_windows(x, core: int, length: int, seg0, ids=None, per_row=None):
+    """Work units' halo windows from a buffer ``x`` (rows, ticks, …), as
+    (units, length, …): unit (k, s) is the ``length`` ticks of row ``k``
+    from tick ``(seg0 + s)·core`` — one contiguous slice per unit, never
+    ``length`` scalar (row, tick) gathers (on a TPU v5e those take about
+    a hundred times as long as slices of the same bytes).
+
+    ``ids=None`` takes every unit, ``per_row`` segments of each row in
+    row-major order (the full-capacity bucket): static slices of the
+    rows, stacked, with no gather at all.  ``ids=(k_ids, s_ids)`` are
+    traced unit ids (a compacted bucket's ``nonzero``): one gather of
+    whole windows.  Every window starts on a ``core`` boundary, so the
+    rows are cut into ``core``-tick blocks (zero-padded to a whole block)
+    and each unit's slice is the ``ceil(length/core)`` blocks from block
+    ``seg0 + s``, trimmed to ``length`` — a gather whose slice is the
+    window, which the TPU runs natively, where a ``(1, length)``
+    dynamic-slice gather lowers to a loop of one slice per unit."""
+    rows_n, ticks = x.shape[:2]
+    trail = x.shape[2:]
+    if ids is None:
+        span = (per_row - 1) * core + length
+        rows = jax.lax.dynamic_slice_in_dim(x, seg0 * core, span, axis=1)
+        win = jnp.stack([rows[:, j * core:j * core + length]
+                         for j in range(per_row)], axis=1)
+        return win.reshape((rows_n * per_row, length) + trail)
+    k_ids, s_ids = ids
+    n_blk, w_blk = -(-ticks // core), -(-length // core)
+    pad = [(0, 0), (0, n_blk * core - ticks)] + [(0, 0)] * len(trail)
+    blocks = jnp.pad(x, pad).reshape((rows_n, n_blk, core) + trail)
+    zeros = (0,) * (1 + len(trail))
+
+    def one(k, s):
+        return jax.lax.dynamic_slice(
+            blocks, (k, seg0 + s) + zeros, (1, w_blk, core) + trail)[0]
+
+    win = jax.vmap(one)(k_ids, s_ids)
+    win = win.reshape((win.shape[0], w_blk * core) + trail)
+    return win[:, :length]
+
+
 class Runner:
     """Chunked streaming execution under one :class:`ExecPolicy`.
 
@@ -640,6 +680,9 @@ class Runner:
         U_loc = self._U // self.policy.n_shards
 
         full_cap = cap == U_loc
+        # segments per buffer row: a keyed shard holds whole keys, a
+        # single-keyed shard U_loc segments of the one (replicated) row
+        per_row = n_segs if keyed else U_loc
 
         def one(*f):
             return outs_fn(dict(zip(names, f)))
@@ -649,34 +692,33 @@ class Runner:
         # every branch of the capacity ladder carries them
         def local(w, *flat):
             with jax.named_scope("tilt.compact"):
+                # a single-keyed shard's segments start at its offset in
+                # the replicated buffer
+                seg0 = (jax.lax.axis_index(axis) * U_loc
+                        if mesh is not None and not keyed else 0)
                 if full_cap:
                     # full-capacity bucket (count > U_loc/2): compaction
                     # saves nothing, so compute every unit in place —
-                    # static ids, no nonzero, identity scatter.
+                    # static window slices, no nonzero, identity scatter.
                     # Bit-identical: computing a clean unit yields exactly
                     # its hold value (the sparse exactness contract), and
                     # the hold fill downstream still overwrites clean units
                     # from the dirty chain.
-                    ids = jnp.arange(cap)
+                    ids, pos = None, None
                 else:
-                    ids = jnp.nonzero(w, size=cap, fill_value=0)[0]
-                if keyed:
-                    k_ids, s_ids = ids // n_segs, ids % n_segs
-                else:
-                    base = (jax.lax.axis_index(axis) * U_loc
-                            if mesh is not None else 0)
-                    k_ids, s_ids = jnp.zeros_like(ids), ids + base
-                pos = (None if full_cap
-                       else jnp.clip(jnp.cumsum(w) - 1, 0, cap - 1))
+                    nz = jnp.nonzero(w, size=cap, fill_value=0)[0]
+                    ids = (nz // per_row, nz % per_row)
+                    pos = jnp.clip(jnp.cumsum(w) - 1, 0, cap - 1)
             with jax.named_scope("tilt.gather"):
                 gath = []
                 for name, (bv, bm) in zip(names, flat):
                     s = specs[name]
-                    tidx = (s_ids[:, None] * s.core
-                            + jnp.arange(s.length)[None, :])
-                    gath.append((
-                        _tm(lambda x: x[k_ids[:, None], tidx], bv),
-                        bm[k_ids[:, None], tidx]))
+
+                    def win(x, s=s):
+                        return _unit_windows(x, s.core, s.length, seg0,
+                                             ids=ids, per_row=per_row)
+
+                    gath.append((_tm(win, bv), win(bm)))
             with jax.named_scope("tilt.compute"):
                 outs = jax.vmap(one)(*gath)              # {o: (cap, S_o, …)}
             if full_cap:

@@ -388,3 +388,112 @@ def test_hypothesis_random_change_masks_never_alter_outputs():
         _assert_same(ref, got, f"seed={seed}")
 
     prop()
+
+
+# -- unit-window gather: contiguous slices ≡ the scalar (key, tick) index ----
+
+def _scalar_windows(x, core, length, seg0, ids=None, per_row=None):
+    """The unit-window gather as one scalar ``(key, tick)`` index pair per
+    window element (``x[k_ids[:, None], tidx]``) — the reference the
+    slice-shaped gather must reproduce bit for bit."""
+    if ids is None:
+        u = jnp.arange(x.shape[0] * per_row)
+        ids = (u // per_row, u % per_row)
+    k_ids, s_ids = ids
+    tidx = (seg0 + s_ids)[:, None] * core + jnp.arange(length)[None, :]
+    return x[k_ids[:, None], tidx]
+
+
+# (rows, per_row segments of a shard, buffer segments, seg0, trailing dims):
+# keyed (8 keys, whole keys per shard); a single-keyed shard at a segment
+# offset of the replicated buffer; a value leaf with a trailing dim
+WINDOW_LAYOUTS = {"keyed": (8, 4, 4, 0, ()),
+                  "single_offset": (1, 4, 16, 8, ()),
+                  "trailing": (8, 4, 4, 0, (3,))}
+WINDOW_CASES = [(lay, cap) for lay, (rows, per_row, *_) in
+                WINDOW_LAYOUTS.items()
+                for cap in sp.capacity_ladder(rows * per_row)]
+
+
+@pytest.mark.parametrize("layout,cap", WINDOW_CASES)
+def test_unit_windows_match_scalar_index_gather(layout, cap):
+    """Every bucket of the capacity ladder: the full-capacity bucket's
+    static slices and the compacted buckets' window gather (ids as
+    ``nonzero`` leaves them: sorted, padded with unit 0) equal the scalar
+    index gather bit for bit, values and validity."""
+    from repro.engine.runner import _unit_windows
+    rows, per_row, n_buf, seg0, trail = WINDOW_LAYOUTS[layout]
+    core, hl = 8, 13                       # a halo that is not whole blocks
+    length, ticks = hl + core, hl + n_buf * core
+    rng = np.random.default_rng(cap)
+    xv = jnp.asarray(rng.standard_normal((rows, ticks) + trail),
+                     jnp.float32)
+    xm = jnp.asarray(rng.random((rows, ticks)) < 0.5)
+    n_units = rows * per_row
+    if cap == n_units:
+        ids = None
+    else:
+        live = np.sort(rng.choice(n_units, max(cap - 1, 1), replace=False))
+        nz = np.zeros(cap, np.int32)
+        nz[:len(live)] = live
+        ids = (jnp.asarray(nz // per_row), jnp.asarray(nz % per_row))
+    for x in (xv, xm):
+        got = _unit_windows(x, core, length, seg0, ids=ids, per_row=per_row)
+        ref = _scalar_windows(x, core, length, seg0, ids=ids,
+                              per_row=per_row)
+        assert got.dtype == x.dtype
+        assert got.shape == ref.shape == (cap, length) + x.shape[2:]
+        assert np.array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("keyed", [True, False])
+def test_runner_windows_bit_identical_to_scalar_index_gather(
+        keyed, monkeypatch):
+    """The fused sparse step with the slice-shaped window gather emits
+    exactly what the same step built on the scalar index gather emits,
+    over chunks whose dirty counts land in compacted buckets and in the
+    full-capacity one."""
+    from repro.engine import ExecPolicy, Runner
+    from repro.engine import runner as runner_mod
+    K = 8 if keyed else 1
+    P, core, n_chunks = 4, 32, 6
+    T = n_chunks * P * core
+    rng = np.random.default_rng(21)
+    vals = np.zeros((K, T), np.float32)
+    valid = np.ones((K, T), bool)
+    rates = np.repeat([0.3, 0.0, 0.004, 0.02, 0.002, 0.3], P * core)
+    for k in range(K):
+        change = rng.random(T) < rates
+        change[0] = True
+        raw = np.floor(rng.random(T) * 100).astype(np.float32)
+        vals[k] = raw[np.maximum.accumulate(
+            np.where(change, np.arange(T), -1))]
+        valid[k, rng.integers(0, T - 8):][:8] = False
+
+    def run():
+        q = _trend(TStream.source("in", keyed=keyed))
+        exe = qc.compile_query(q.node, out_len=core, pallas=False,
+                               sparse=True)
+        r = Runner(exe, ExecPolicy(body="sparse",
+                                   keys="vmapped" if keyed else "single"),
+                   n_keys=K if keyed else None, segs_per_chunk=P)
+        outs, counts = [], []
+        for c in range(n_chunks):
+            sl = slice(c * P * core, (c + 1) * P * core)
+            g = (keyed_grid(vals[:, sl], valid[:, sl], t0=sl.start)
+                 if keyed else _grid(vals[0, sl], valid[0, sl], t0=sl.start))
+            o = r.step({"in": g})
+            outs.append((np.asarray(o.value), np.asarray(o.valid)))
+            counts.append(int(np.asarray(r.last_seg_dirty).sum()))
+        return outs, counts
+
+    got, counts = run()
+    monkeypatch.setattr(runner_mod, "_unit_windows", _scalar_windows)
+    ref, ref_counts = run()
+    assert counts == ref_counts
+    U = K * P
+    buckets = {sp.bucket_capacity(c, U) for c in counts}
+    assert U in buckets and min(buckets) < U, (counts, buckets)
+    for (gv, gm), (rv, rm) in zip(got, ref):
+        assert np.array_equal(gm, rm)
+        assert np.array_equal(gv, rv)

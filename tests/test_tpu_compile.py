@@ -174,3 +174,45 @@ def test_staged_steps_carry_names_and_scopes_on_v5e(one_chip, monkeypatch,
         else:
             assert scopes == SPARSE_SCOPES
             assert [k for k in kernels if "seg_dirty" in k], kernels
+
+
+@pytest.mark.parametrize("label,name", [
+    ("sparse_fused(steady)", "tilt_sparse_steady"),
+    ("revise", "tilt_revision_step")])
+def test_unit_window_gather_moves_whole_windows_on_v5e(one_chip, monkeypatch,
+                                                       label, name):
+    """The unit-window gather (scope ``tilt.gather``) of the steps that
+    compact, compiled for the described chip at fraud's geometry: each
+    compacted branch gathers whole windows (a slice of at least the window's
+    length per index, never one tick per ``(key, tick)`` pair), no loop of
+    per-unit slices stands in for a gather, and the full-capacity branch
+    gathers nothing (its windows are static slices)."""
+    import math
+    import re
+
+    from repro.core import sparse as sp
+    from repro.engine import ExecPolicy, Runner
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    exe = qc.compile_query(make_keyed_app("fraud", win=1000).query.node,
+                           out_len=256, sparse=True)
+    runner = Runner(exe, ExecPolicy(body="sparse", keys="vmapped"), n_keys=8,
+                    segs_per_chunk=4)
+    runner.enable_revision(1)
+    (step,) = [s for s in runner.staged_steps() if s["label"] == label]
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        step["args"])
+    text = step["fn"].lower(*shapes).compile().as_text()
+    assert text.startswith(f"HloModule jit_{name},")
+    length = min(s.length for s in runner.spec.input_specs.values())
+    full = len(sp.capacity_ladder(runner.n_keys * runner.n_segs)) - 1
+    gathers = re.findall(r" gather\(.*slice_sizes=\{([0-9,]*)\}"
+                         r'.*op_name="([^"]*tilt\.gather[^"]*)"', text)
+    assert gathers, "no compacted branch gathers its windows"
+    for sizes, op in gathers:
+        assert math.prod(int(n) for n in sizes.split(",")) >= length, (
+            sizes, op)
+        assert f"/branch_{full}_fun/" not in op, op
+    assert not re.findall(r' while\(.*op_name="[^"]*tilt\.gather', text)
