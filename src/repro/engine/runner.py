@@ -298,6 +298,10 @@ class Runner:
             "units")
         self._m_keys = m.gauge("runner.keys", "keyed sub-streams", "keys")
         self._m_keys.set(self.n_keys)
+        self._m_ingest_puts = m.counter(
+            "runner.ingest_puts",
+            "chunk leaves the step itself put on the mesh's ingest "
+            "sharding (0 when the caller committed them ahead)", "leaves")
         self._m_donated = m.counter(
             "runner.donated_steps",
             "steps run through a buffer-donating jitted step", "steps")
@@ -457,12 +461,37 @@ class Runner:
     def _names(self):
         return sorted(self.spec.input_specs)
 
+    def ingest_sharding(self) -> Optional[NamedSharding]:
+        """The sharding :meth:`step` ingests a chunk's leaves with under
+        mesh placement: split by key over the mesh axis when keyed (each
+        device holds its own keys' rows, as it holds their carried state),
+        a full copy on every device when single-keyed (every shard's
+        windows read the one buffer).  ``None`` under local placement,
+        where a chunk is taken where it lies.  A caller that commits
+        chunks to it ahead of :meth:`step` (the serve loop's double
+        buffer) spares the step a transfer of its own."""
+        mesh = self.policy.mesh
+        if mesh is None:
+            return None
+        return NamedSharding(
+            mesh, P(self.policy.axis) if self.policy.keyed else P())
+
     def _place(self, tree):
         """Device placement of carried per-key state (key-axis sharding)."""
-        if self.policy.mesh is None or not self.policy.keyed:
+        sh = self.ingest_sharding()
+        if sh is None or not self.policy.keyed:
             return tree
-        sh = NamedSharding(self.policy.mesh, P(self.policy.axis))
         return _tm(lambda x: jax.device_put(x, sh), tree)
+
+    def _commit(self, x, sh):
+        """One chunk leaf on the ingest sharding ``sh``: an array already
+        there is taken as it is; anything else is put here, one explicit
+        transfer, counted in ``runner.ingest_puts``."""
+        if isinstance(x, jax.Array) and x.sharding.is_equivalent_to(
+                sh, x.ndim):
+            return x
+        self._m_ingest_puts.add(1)
+        return jax.device_put(x, sh)
 
     def _unit_flags(self, flags):
         """Pin the per-unit flags ``(K, n_segs)`` (the sparse step's
@@ -551,6 +580,7 @@ class Runner:
     # -- chunk ingest --------------------------------------------------------
     def _ingest(self, chunks: Dict[str, SnapshotGrid]) -> Dict[str, tuple]:
         chunk_in = {}
+        sh = self.ingest_sharding()
         for name in self._names():
             s = self.spec.input_specs[name]
             g = chunks[name]
@@ -561,9 +591,11 @@ class Runner:
                     f"input {name}: chunk validity shape "
                     f"{tuple(g.valid.shape)} != expected {want}")
             v, m = g.value, g.valid
+            if sh is not None:
+                v, m = _tm(lambda x: self._commit(x, sh), (v, m))
             if not self.policy.keyed:  # internal layout always carries K
                 v, m = _tm(lambda x: x[None], v), m[None]
-            chunk_in[name] = self._place((v, m))
+            chunk_in[name] = (v, m)
         return chunk_in
 
     def _init_missing_tails(self, chunk_in: Dict[str, tuple]) -> None:

@@ -9,10 +9,13 @@
 * :meth:`serve` is the chunk path: chunk k+1's ``jax.device_put``
   (committed, non-blocking) is issued *before* chunk k's compute
   dispatch, so the H2D transfer of the next request overlaps the current
-  step.  Every transfer on the steady-state path is explicit, so the
-  whole loop runs under ``jax.transfer_guard("disallow")`` (pinned in
-  tests/test_serve.py); the staged step's donation contract recycles the
-  carried state buffers in place.
+  step — to the serving device, or under mesh placement to the runner's
+  ingest sharding (:meth:`repro.engine.Runner.ingest_sharding`), so the
+  step finds every chunk already placed over the mesh.  Every transfer
+  on the steady-state path is explicit, so the whole loop runs under
+  ``jax.transfer_guard("disallow")`` (pinned in tests/test_serve.py);
+  the staged step's donation contract recycles the carried state
+  buffers in place.
 * :meth:`attach_events` + :meth:`offer` / :meth:`pump` is the event
   path: a fixed-capacity :class:`repro.serve.ring.AdmissionRing` feeds
   the disorder-tolerant :class:`repro.ingest.IngestRunner` (watermarks
@@ -97,12 +100,14 @@ class ServeLoop:
         self.runner = runner
         self.exec_cache = exec_cache
         self.query_fp = query_fp
-        # local placement: commit chunks to the device ahead of dispatch
-        # (the double buffer); mesh placement keeps the runner's own
-        # sharded ingest placement
-        self._device = (None if runner.policy.mesh is not None
-                        else (device if device is not None
-                              else jax.devices()[0]))
+        # the double buffer commits each chunk ahead of dispatch to where
+        # the step reads it: the serving device under local placement, the
+        # runner's own ingest sharding under mesh placement
+        sharding = runner.ingest_sharding()
+        self._dst = (sharding if sharding is not None
+                     else device if device is not None
+                     else jax.devices()[0])
+        self._shards = len(sharding.device_set) if sharding is not None else 1
         m = self.metrics = runner.metrics
         self._tracer = m.tracer
         self._seq = 0  # chunk sequence number: the spans' ``chunk`` id
@@ -114,6 +119,9 @@ class ServeLoop:
             "serve.admit_to_result_seconds",
             log_buckets(1e-5, 100.0, per_decade=2),
             "ring admission to sealed-result latency", "s", log_scale=True)
+        self._m_put_bytes = m.counter(
+            "serve.put_bytes", "host bytes the loop committed to the "
+            "device(s) ahead of the step's dispatch", "bytes")
         self._m_first = m.gauge(
             "serve.first_result_seconds",
             "construction to first blocked result", "s")
@@ -135,19 +143,28 @@ class ServeLoop:
     # -- chunk path ----------------------------------------------------------
     def _put(self, chunks: Dict[str, SnapshotGrid], **ids
              ) -> Dict[str, SnapshotGrid]:
-        """Commit one request's grids to the serving device — an explicit
-        (transfer-guard-legal) non-blocking H2D; issued for chunk k+1
-        before chunk k's compute dispatch so the transfer overlaps.  The
-        ``serve.put`` span times the host's enqueue, not the transfer."""
-        if self._device is None:
-            return chunks
-        d = self._device
-        with self._tracer.span("serve.put", **ids):
-            return {name: SnapshotGrid(
-                        value=_tm(lambda x: jax.device_put(x, d), g.value),
-                        valid=jax.device_put(g.valid, d), t0=g.t0,
-                        prec=g.prec)
-                    for name, g in chunks.items()}
+        """Commit one request's grids to the serving device, or under mesh
+        placement to the runner's ingest sharding (over its ``shards``
+        devices) — an explicit (transfer-guard-legal) non-blocking H2D;
+        issued for chunk k+1 before chunk k's compute dispatch so the
+        transfer overlaps.  The ``serve.put`` span times the host's
+        enqueue, not the transfer; ``serve.put_bytes`` counts the host
+        bytes it committed."""
+        dst, host = self._dst, 0
+
+        def put(x):
+            nonlocal host
+            if not isinstance(x, jax.Array):
+                host += x.nbytes
+            return jax.device_put(x, dst)
+
+        with self._tracer.span("serve.put", shards=self._shards, **ids):
+            staged = {name: SnapshotGrid(
+                          value=_tm(put, g.value), valid=put(g.valid),
+                          t0=g.t0, prec=g.prec)
+                      for name, g in chunks.items()}
+        self._m_put_bytes.add(host)
+        return staged
 
     def _put_next(self, chunks: Dict[str, SnapshotGrid]) -> tuple:
         """``(chunk id, staged grids)``: the put of the next chunk of the

@@ -13,6 +13,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,10 +28,10 @@ from repro.kernels import window_reduce as wr
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described v5e:2x2 host, with jax's persistent
-    compilation cache off (a described-chip compile could be written to
-    it but never read back here)."""
+def topo():
+    """A described v5e:2x2 host, with jax's persistent compilation cache
+    off (a described-chip compile could be written to it but never read
+    back here)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -43,9 +44,15 @@ def one_chip():
     except Exception as e:  # no TPU compiler in this installation
         jax.config.update("jax_enable_compilation_cache", was_on)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One device of the described host."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_has_kernel(fn, one_chip, *shapes) -> bool:
@@ -141,7 +148,6 @@ def test_staged_steps_carry_names_and_scopes_on_v5e(one_chip, monkeypatch,
     reads as ``tf_op``), the change-detection kernel is the custom call
     ``seg_dirty`` and the window kernels keep their ``jit_sliding_*``
     names."""
-    import re
 
     from repro.engine import ExecPolicy, Runner
     from repro.kernels import ops
@@ -188,7 +194,6 @@ def test_unit_window_gather_moves_whole_windows_on_v5e(one_chip, monkeypatch,
     per-unit slices stands in for a gather, and the full-capacity branch
     gathers nothing (its windows are static slices)."""
     import math
-    import re
 
     from repro.core import sparse as sp
     from repro.engine import ExecPolicy, Runner
@@ -216,3 +221,49 @@ def test_unit_window_gather_moves_whole_windows_on_v5e(one_chip, monkeypatch,
             sizes, op)
         assert f"/branch_{full}_fun/" not in op, op
     assert not re.findall(r' while\(.*op_name="[^"]*tilt\.gather', text)
+
+
+COLLECTIVE = re.compile(r"\b(all-gather|all-reduce|collective-permute|"
+                        r"all-to-all|reduce-scatter)(-start)?\(")
+
+
+def test_keyed_mesh_sparse_steps_hold_no_collective_on_v5e(topo,
+                                                           monkeypatch):
+    """The keyed sparse steps under mesh placement, first and steady,
+    compiled for all four devices of the described host at ``fraud_x4``'s
+    per-key geometry (window 1000, 8 × 256-tick segments; 16 cards a
+    chip): keys never communicate, so no cross-chip collective may appear
+    anywhere in a step — change detection and the compute sit inside
+    ``shard_map``, the hold fill is partitioned by key — and each device's
+    program still runs the change-detection kernel."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.engine import ExecPolicy, Runner
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    assert mesh.devices.size == 4
+    exe = qc.compile_query(make_keyed_app("fraud", win=1000).query.node,
+                           out_len=256, sparse=True)
+    keys = 64
+    # argument shapes from a local twin (every leaf leads with the key axis)
+    twin = Runner(exe, ExecPolicy(body="sparse", keys="vmapped"), n_keys=4,
+                  segs_per_chunk=8)
+    runner = Runner(exe, ExecPolicy(body="sparse", keys="vmapped",
+                                    placement=mesh),
+                    n_keys=keys, segs_per_chunk=8)
+    split = NamedSharding(mesh, P("data"))
+    for step in twin.staged_steps():
+        if not step["label"].startswith("sparse_fused"):
+            continue
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((keys,) + x.shape[1:], x.dtype,
+                                           sharding=split), step["args"])
+        fn = runner._fused_sparse_step(step["label"].endswith("(first)"))
+        text = fn.lower(*shapes).compile().as_text()
+        assert not COLLECTIVE.findall(text), (
+            step["label"], [ln.strip()[:200] for ln in text.splitlines()
+                            if COLLECTIVE.search(ln)])
+        assert "seg_dirty" in text and "tpu_custom_call" in text
